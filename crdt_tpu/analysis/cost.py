@@ -70,7 +70,8 @@ def _var_key(v):
 
 def _walk(jaxpr):
     """(peak_bytes, collective_bytes, eqns) for one (open) jaxpr."""
-    from jax import core as jcore
+    import jax
+    from jax.extend import core as jcore
 
     last_use: Dict[int, int] = {}
     n_eqns = len(jaxpr.eqns)
@@ -104,13 +105,13 @@ def _walk(jaxpr):
 
         out_bytes = sum(
             _aval_bytes(v.aval) for v in eqn.outvars
-            if not isinstance(v, jcore.DropVar)
+            if not isinstance(v, jax.core.DropVar)
         )
         if prim in _CLOBBER_PRIMS:
             coll += out_bytes * trip
 
         for v in eqn.outvars:
-            if isinstance(v, jcore.DropVar):
+            if isinstance(v, jax.core.DropVar):
                 continue
             k = _var_key(v)
             if k not in live:
@@ -119,7 +120,7 @@ def _walk(jaxpr):
         peak = max(peak, live_bytes + sub_peak)
 
         for v in list(eqn.invars) + list(eqn.outvars):
-            if isinstance(v, (jcore.Literal, jcore.DropVar)):
+            if isinstance(v, (jcore.Literal, jax.core.DropVar)):
                 continue
             k = _var_key(v)
             if last_use.get(k, -1) <= i and k in live:

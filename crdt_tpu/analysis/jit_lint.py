@@ -71,7 +71,7 @@ from typing import Any, List, Optional, Sequence, Set
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from .report import Finding, slice_jaxpr
 
@@ -298,7 +298,7 @@ class _Walker:
                 if out_exact:
                     exact.update(
                         v for v in eqn.outvars
-                        if not isinstance(v, jcore.DropVar)
+                        if not isinstance(v, jax.core.DropVar)
                     )
 
             # Recurse into sub-programs, mapping provenance positionally

@@ -70,7 +70,7 @@ class SectionResult:
 
 def summarize(sections: Sequence[SectionResult]) -> dict:
     """The machine-readable runner summary (per-section pass/fail,
-    finding counts, wall-clock) CI and VERDICT rounds trend instead of
+    finding counts, wall-clock) CI and review rounds trend instead of
     parsing the text output. Stable shape: top-level ``ok`` /
     ``total_seconds`` / ``sections``; per section ``ok`` / ``seconds``
     / ``errors`` / ``warnings`` / ``checks`` (the sorted set of firing

@@ -1,5 +1,5 @@
 """Actor lifecycle mechanics: counter widening and retirement
-(VERDICT r04 Missing #5; SURVEY.md §7.3 overflow discipline).
+(SURVEY.md §7.3 overflow discipline).
 
 The reference sidesteps saturation by being u64 end to end
 (src/vclock.rs ``BTreeMap<A, u64>``); the device lattice defaults to

@@ -2,8 +2,8 @@
 replicas on device.
 
 The sparse counterpart of :class:`.map_nested.BatchedMapOrswot` for key
-universes where the dense K×M slab stops scaling (VERDICT r04 Missing
-#2; reference: src/map.rs ``Map<K, V: Val<A>, A>``): state tracks LIVE
+universes where the dense K×M slab stops scaling (reference: src/map.rs
+``Map<K, V: Val<A>, A>``): state tracks LIVE
 (key, member, actor) cells plus parked-remove LISTS, never a K×M cube.
 Flattening matches the dense model (cell id = key_id · span +
 member_id, global member interner) so the two backends are directly

@@ -17,6 +17,7 @@ keeps the API alive at oracle speed.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -27,21 +28,27 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "listseq.cpp")
-_LIB = os.path.join(_DIR, "_listseq.so")
+with open(_SRC, "rb") as _f:
+    # The library is named after the source it was built from, so a
+    # stale .so copied along with the tree (whatever its mtime) is never
+    # loaded: any edit to listseq.cpp names a new library.
+    _LIB = os.path.join(
+        _DIR, f"_listseq-{hashlib.sha256(_f.read()).hexdigest()[:16]}.so"
+    )
 
 
 _BUILD_FAILED = False
 
 
 def _build() -> Optional[str]:
-    """Compile listseq.cpp → _listseq.so if stale/missing. Returns the
+    """Compile listseq.cpp → _listseq-<hash>.so if missing. Returns the
     library path, or None if no toolchain is available. A failure is
     cached so repeated engine constructions don't re-spawn g++."""
     global _BUILD_FAILED
     if _BUILD_FAILED:
         return None
     try:
-        if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
+        if os.path.exists(_LIB):
             return _LIB
         # Build to a temp name then rename: atomic for concurrent pytest
         # workers sharing the checkout.

@@ -1,7 +1,7 @@
 """Sparse (segment-encoded) map nesting — the NestLevel induction for
 the compressed representation.
 
-Round 4 left the sparse backend flat (VERDICT r04 Missing #2): the
+Round 4 left the sparse backend flat: the
 segment-encoded ORSWOT scaled to huge member universes, but the map
 family was dense-only, so ``Map<K, Orswot>`` at 1M keys could not fit
 the E×A slab. This module is the sparse counterpart of ops/nest.py's

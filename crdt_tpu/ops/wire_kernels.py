@@ -104,69 +104,69 @@ class WirePackOut(NamedTuple):
 
 def _wire_kernel(spec: WireLaneSpec, n: int, rc: int, *refs):
     """The fused pass, one row chunk per program. Positional refs
-    (presence by spec flags): clocks [RC, LC2], base [RC, LC2],
-    valid [RC, 1], [know [RC, A], dig [RC, A]] when gated,
-    [winc [RC, LC2], ack_ok [RC, 1]] when acked, then outputs:
-    out [RC, W], keep/defer/cov [RC, 1], stats [1, 8] (the same
-    revisited block across the sequential row-chunk grid — the
-    standard TPU reduction pattern the fold kernel uses). ``n`` is the
-    UNPADDED row count — checksum weights must match the shipped
-    (unpadded) leaf's flat lane order, so rows are indexed globally
-    via the program id."""
+    (presence by spec flags): the clock columns split into halves lo
+    [RC, W] (columns ``[0, W)``) and hi [RC, W] (columns ``[W, 2W)``),
+    the watermark halves blo/bhi [RC, W], valid [RC, 1], [ctxs [RC, A],
+    know [RC, A], dig [RC, A]] when gated, [winc halves wlo/whi [RC, W],
+    ack_ok [RC, 1]] when acked, then outputs: out [RC, W],
+    keep/defer/cov [RC, 1], stats [1, 8] (the same revisited block
+    across the sequential row-chunk grid — the standard TPU reduction
+    pattern the fold kernel uses). The wrapper splits the columns: a
+    lane slice at an offset inside the 128-lane tile (``x[:, W:2W]``)
+    reads the wrong lanes under Mosaic, so the kernel slices nothing.
+    ``n`` is the UNPADDED row count — checksum weights must match the
+    shipped (unpadded) leaf's flat lane order, so rows are indexed
+    globally via the program id."""
     i = 0
-    clocks = refs[i][:]; i += 1
-    base = refs[i][:]; i += 1
+    lo = refs[i][:]; i += 1
+    hi = refs[i][:]; i += 1
+    blo = refs[i][:]; i += 1
+    bhi = refs[i][:]; i += 1
     valid = refs[i][:] != 0; i += 1
     if spec.gated:
+        ctxs = refs[i][:]; i += 1
         know = refs[i][:]; i += 1
         dig = refs[i][:]; i += 1
     if spec.acked:
-        winc = refs[i][:]; i += 1
+        wlo = refs[i][:]; i += 1
+        whi = refs[i][:]; i += 1
         ack_ok = refs[i][:] != 0; i += 1
     out_ref, keep_ref, defer_ref, cov_ref, stats_ref = refs[i:]
 
-    ct = clocks.dtype
-    lc2 = clocks.shape[-1]
-    w = lc2 // 2
+    ct = lo.dtype
+    w = lo.shape[-1]
+
+    def all_lanes(x):
+        return jnp.min(x.astype(jnp.int32), axis=-1, keepdims=True) != 0
 
     # ---- encode: biased-u16 delta vs the watermark, one read --------
-    encb = clocks - base + jnp.asarray(BIAS, ct)   # wraps in ct
-    fits = encb < jnp.asarray(_U16, ct)
-    # Padded columns hold clocks == base == 0 -> encb == BIAS: fits.
-    fit_slot = jnp.min(fits.astype(jnp.int32), axis=-1, keepdims=True) != 0
+    enc_lo = lo - blo + jnp.asarray(BIAS, ct)   # wraps in ct
+    enc_hi = hi - bhi + jnp.asarray(BIAS, ct)
+    # Padded columns hold clocks == base == 0 -> enc == BIAS: fits.
+    fit_slot = (all_lanes(enc_lo < jnp.asarray(_U16, ct))
+                & all_lanes(enc_hi < jnp.asarray(_U16, ct)))
 
     # ---- gate verdicts (the delta.gate_delta / ackwin.gate_window
     # rules, evaluated on the same resident lanes) --------------------
     if spec.gated:
-        ctxs = clocks[:, spec.ctx_lo:spec.ctx_hi]
-        addonly = jnp.min(
-            (ctxs == know).astype(jnp.int32), axis=-1, keepdims=True
-        ) != 0
-        under = jnp.min(
-            (know <= dig).astype(jnp.int32), axis=-1, keepdims=True
-        ) != 0
-        cov_d = valid & addonly & under
+        cov_d = valid & all_lanes(ctxs == know) & all_lanes(know <= dig)
     else:
         cov_d = jnp.zeros_like(valid)
     if spec.acked:
         # Content columns are every clock column OUTSIDE the ctx range
         # (padding columns compare equal by construction); the ctx
         # columns check coverage instead of equality.
-        is_ctx = (
-            (jax.lax.broadcasted_iota(jnp.int32, (1, lc2), 1)
-             >= spec.ctx_lo)
-            & (jax.lax.broadcasted_iota(jnp.int32, (1, lc2), 1)
-               < spec.ctx_hi)
-        )
-        same = jnp.min(
-            jnp.where(is_ctx, 1, (clocks == winc).astype(jnp.int32)),
-            axis=-1, keepdims=True,
-        ) != 0
-        covc = jnp.min(
-            jnp.where(is_ctx, (clocks <= winc).astype(jnp.int32), 1),
-            axis=-1, keepdims=True,
-        ) != 0
-        cov_a = valid & ~cov_d & ack_ok & same & covc
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+
+        def half_ok(x, wx, first):
+            is_ctx = (col + first >= spec.ctx_lo) & (col + first < spec.ctx_hi)
+            # int32 selects: Mosaic cannot narrow a selected i8 to i1.
+            same = all_lanes(jnp.where(is_ctx, 1, (x == wx).astype(jnp.int32)))
+            covc = all_lanes(jnp.where(is_ctx, (x <= wx).astype(jnp.int32), 1))
+            return same & covc
+
+        cov_a = (valid & ~cov_d & ack_ok & half_ok(lo, wlo, 0)
+                 & half_ok(hi, whi, w))
     else:
         cov_a = jnp.zeros_like(valid)
 
@@ -174,29 +174,33 @@ def _wire_kernel(spec: WireLaneSpec, n: int, rc: int, *refs):
     defer = valid & ~cov_d & ~cov_a & ~fit_slot
 
     # ---- masked pack: two u16 lanes per u32 word (half-split) -------
-    enc = jnp.where(keep & fits, encb, jnp.zeros_like(encb)).astype(
-        jnp.uint32
-    ) & jnp.uint32(0xFFFF)
-    words = enc[:, :w] | (enc[:, w:2 * w] << 16)
+    def masked(enc):
+        return jnp.where(keep, enc, jnp.zeros_like(enc)).astype(
+            jnp.uint32
+        ) & jnp.uint32(0xFFFF)
+
+    words = masked(enc_lo) | (masked(enc_hi) << 16)
 
     # ---- checksum partial + packed-word count, same read ------------
     # Weights replicate integrity._lanes_u32's flat order over the
     # UNPADDED [n, w] leaf; padded rows contribute zero values, so
-    # their (out-of-range) weights multiply zeros.
+    # their (out-of-range) weights multiply zeros. Mosaic reduces only
+    # signed integers, so the sums run in int32: two's-complement
+    # wrap-around gives the same bits as the u32 modular sum.
     row0 = pl.program_id(0) * rc
     r_ix = row0 + jax.lax.broadcasted_iota(jnp.int32, words.shape, 0)
     c_ix = jax.lax.broadcasted_iota(jnp.int32, words.shape, 1)
-    wts = (jnp.uint32(2) * (r_ix * w + c_ix).astype(jnp.uint32)
-           + jnp.uint32(1))
-    chk = jnp.sum(words * wts, dtype=jnp.uint32)
-    nnz = jnp.sum((words != 0).astype(jnp.uint32), dtype=jnp.uint32)
+    wts = 2 * (r_ix * w + c_ix) + 1
+    chk = jnp.sum(words.astype(jnp.int32) * wts, dtype=jnp.int32)
+    nnz = jnp.sum((words != 0).astype(jnp.int32), dtype=jnp.int32)
 
     out_ref[:] = words
     keep_ref[:] = keep.astype(jnp.int32)
     defer_ref[:] = defer.astype(jnp.int32)
     cov_ref[:] = cov_a.astype(jnp.int32)
-    stats = jnp.zeros((1, 8), jnp.uint32)
-    stats = stats.at[0, 0].set(nnz).at[0, 1].set(chk)
+    # No in-kernel scatter on Mosaic: place the two stats by lane select.
+    col8 = jax.lax.broadcasted_iota(jnp.int32, (1, 8), 1)
+    stats = jnp.where(col8 == 0, nnz, jnp.where(col8 == 1, chk, 0))
 
     first = pl.program_id(0) == 0
 
@@ -207,6 +211,23 @@ def _wire_kernel(spec: WireLaneSpec, n: int, rc: int, *refs):
     @pl.when(jnp.logical_not(first))
     def _acc():
         stats_ref[:] = stats_ref[:] + stats
+
+
+def _lanes(cols: int) -> int:
+    """Columns of one VMEM block row once padded to the 128-lane tile."""
+    return -(-cols // 128) * 128
+
+
+def _row_vmem_bytes(spec: WireLaneSpec, a: int) -> int:
+    """Lane-padded 4-byte words per row over every block the kernel
+    streams: the clock and watermark halves, valid, [ctxs, know, dig],
+    [winc halves, ack_ok], and the four outputs."""
+    lanes = 5 * _lanes(spec.w) + 4 * _lanes(1)
+    if spec.gated:
+        lanes += 3 * _lanes(a)
+    if spec.acked:
+        lanes += 2 * _lanes(spec.w) + _lanes(1)
+    return 4 * lanes
 
 
 def wire_pack(
@@ -234,12 +255,13 @@ def wire_pack(
         interpret = not _fused_backend()
     n, lc = clocks.shape
     assert lc == spec.lc, (lc, spec.lc)
-    lc2 = 2 * spec.w
+    w = spec.w
     a = max(spec.ctx_hi - spec.ctx_lo, 1)
     # Row-chunk the grid via the shared autotune table, keyed on the
     # WIRE family so fold-family sweeps are never silently reused
     # (tools/tile_table.json; tests/test_wire.py pins the key split).
-    rc = _pick_r_chunk(n, a, lc2, None, family="wire")
+    rc = _pick_r_chunk(n, a, 2 * w, None, family="wire",
+                       row_bytes=_row_vmem_bytes(spec, a))
     steps = (n + rc - 1) // rc
     pad_r = steps * rc - n
 
@@ -247,36 +269,29 @@ def wire_pack(
         p = ((0, pad_r), (0, 0 if cols is None else cols - x.shape[-1]))
         return jnp.pad(x, p) if (p[0][1] or p[1][1]) else x
 
-    clocks = padded(clocks, lc2)
-    base = padded(base, lc2)
-    ins = [clocks, base, padded(valid.astype(jnp.int32)[:, None])]
-    row2 = lambda i: (i, 0)
-    in_specs = [
-        pl.BlockSpec((rc, lc2), row2, memory_space=pltpu.VMEM),
-        pl.BlockSpec((rc, lc2), row2, memory_space=pltpu.VMEM),
-        pl.BlockSpec((rc, 1), row2, memory_space=pltpu.VMEM),
+    def halves(x):
+        x = padded(x, 2 * w)
+        return [x[:, :w], x[:, w:]]
+
+    ins = halves(clocks) + halves(base) + [
+        padded(valid.astype(jnp.int32)[:, None])
     ]
     if spec.gated:
-        ins += [padded(know), padded(dig)]
-        in_specs += [
-            pl.BlockSpec((rc, know.shape[-1]), row2,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((rc, dig.shape[-1]), row2,
-                         memory_space=pltpu.VMEM),
-        ]
+        ins += [padded(clocks[:, spec.ctx_lo:spec.ctx_hi]), padded(know),
+                padded(dig)]
     if spec.acked:
-        ins += [padded(winc, lc2),
-                padded(ack_ok.astype(jnp.int32)[:, None])]
-        in_specs += [
-            pl.BlockSpec((rc, lc2), row2, memory_space=pltpu.VMEM),
-            pl.BlockSpec((rc, 1), row2, memory_space=pltpu.VMEM),
-        ]
+        ins += halves(winc) + [padded(ack_ok.astype(jnp.int32)[:, None])]
+    row2 = lambda i: (i, 0)  # noqa: E731
+    in_specs = [
+        pl.BlockSpec((rc, x.shape[-1]), row2, memory_space=pltpu.VMEM)
+        for x in ins
+    ]
     outs = pl.pallas_call(
         partial(_wire_kernel, spec, n, rc),
         grid=(steps,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((rc, spec.w), row2, memory_space=pltpu.VMEM),
+            pl.BlockSpec((rc, w), row2, memory_space=pltpu.VMEM),
             pl.BlockSpec((rc, 1), row2, memory_space=pltpu.VMEM),
             pl.BlockSpec((rc, 1), row2, memory_space=pltpu.VMEM),
             pl.BlockSpec((rc, 1), row2, memory_space=pltpu.VMEM),
@@ -284,11 +299,11 @@ def wire_pack(
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((steps * rc, spec.w), jnp.uint32),
+            jax.ShapeDtypeStruct((steps * rc, w), jnp.uint32),
             jax.ShapeDtypeStruct((steps * rc, 1), jnp.int32),
             jax.ShapeDtypeStruct((steps * rc, 1), jnp.int32),
             jax.ShapeDtypeStruct((steps * rc, 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, 8), jnp.uint32),
+            jax.ShapeDtypeStruct((1, 8), jnp.int32),
         ],
         interpret=interpret,
     )(*ins)
@@ -298,8 +313,8 @@ def wire_pack(
         keep=keep[:n, 0] != 0,
         defer=defer[:n, 0] != 0,
         covered=cov[:n, 0] != 0,
-        nnz=stats[0, 0],
-        chk=stats[0, 1],
+        nnz=stats[0, 0].astype(jnp.uint32),
+        chk=stats[0, 1].astype(jnp.uint32),
     )
 
 

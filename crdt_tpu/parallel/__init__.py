@@ -27,23 +27,6 @@ Top-level entry points (:mod:`.anti_entropy`) wrap these in
 ``jax.shard_map`` over a mesh and are what models/bench/driver call.
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under jax.experimental with the
-    # replication checker flag named check_rep instead of check_vma.
-    # Installed before any submodule import so every entry point sees
-    # the same ``jax.shard_map`` surface regardless of jax version.
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=bool(check_vma), **kw,
-        )
-
-    _jax.shard_map = _shard_map_compat
-
 from .mesh import (
     REPLICA_AXIS,
     ELEMENT_AXIS,

@@ -1,9 +1,8 @@
 """Element sharding for the sparse (segment-encoded) backend.
 
 Round 4's ``mesh_fold_sparse`` reduced the replica axis but left every
-segment table replicated across the element axis (VERDICT r04 Missing
-#2 / Weak #5) — the one representation built for huge universes didn't
-scale by elements. This module is the missing SP analog: partition each
+segment table replicated across the element axis — the one
+representation built for huge universes didn't scale by elements. This module is the missing SP analog: partition each
 replica's segment table by ``eid % n_shards``. The restriction of a
 sparse ORSWOT to an element subuniverse is itself a sparse ORSWOT, and
 every join rule is per-element (cell matching, top subsumption, parked

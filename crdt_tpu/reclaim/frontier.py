@@ -113,7 +113,7 @@ def frontier_lag(top, frontier):
     mesh). The in-jit gauge behind the ``frontier_lag`` telemetry
     field; a growing lag under steady traffic means some replica is
     pinning the frontier (straggler/partition) and reclamation is
-    stalled — the operator signal VERDICT r5 asks for. Pure jnp; lanes
+    stalled — the operator's signal. Pure jnp; lanes
     BEHIND the frontier (an identity-padded row, a restored straggler)
     clamp to 0 rather than wrapping the unsigned difference."""
     import jax.numpy as jnp

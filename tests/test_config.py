@@ -74,7 +74,7 @@ def test_strict_mode_validation():
 
 
 def test_strict_validation_on_xla_backend():
-    # VERDICT r2 #8: a gapped/duplicate dot must raise DotRange on the
+    # A gapped/duplicate dot must raise DotRange on the
     # batched path too, not only through the pure types' validate_op.
     from crdt_tpu.models import BatchedMap, BatchedOrswot
     from crdt_tpu.pure.map import Map

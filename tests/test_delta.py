@@ -231,7 +231,7 @@ def test_delta_converges_for_any_cap(cap, seed):
 
 @pytest.mark.parametrize("seed", [7])
 def test_residue_reports_underbudgeted_run(seed):
-    """VERDICT r04 item #4: a capped backlog with the default P-1 rounds
+    """A capped backlog with the default P-1 rounds
     must REPORT non-convergence at runtime (residue > 0) instead of
     silently returning an under-converged ring — and a properly budgeted
     run of the same workload must report residue == 0."""

@@ -1,4 +1,4 @@
-"""Actor lifecycle mechanics (VERDICT r04 Missing #5): the two
+"""Actor lifecycle mechanics: the two
 remedies CounterSaturation prescribes — u32→u64 widening and
 retired-actor compaction — as migrations that preserve converged state
 bit-identically at the oracle level."""

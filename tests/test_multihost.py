@@ -52,7 +52,7 @@ def test_two_process_mesh_fold_bit_identical():
 
 
 def test_two_process_list_sync():
-    """Multi-host List (VERDICT r04 Missing #4): divergent per-process
+    """Multi-host List: divergent per-process
     edit logs converge after op-log sync — identifier minting is local,
     identifier PATHS ship over the 2-process runtime, and every process
     reads the same sequence."""

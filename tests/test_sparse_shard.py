@@ -1,6 +1,6 @@
 """Element-sharded sparse folds vs the unsharded path — the SP analog
-for the segment-encoded backend (VERDICT r04 Missing #2: 'shard segment
-tables across the element axis'). Restriction commutes with the join,
+for the segment-encoded backend: segment tables sharded across the
+element axis. Restriction commutes with the join,
 so the sharded mesh fold must reproduce the unsharded fold exactly on
 content, tops, and the parked-remove SET (slot packing may differ per
 shard — each shard is its own restricted CRDT)."""

@@ -34,7 +34,7 @@ def _edit_trace(rng, n_ops, n_actors=3):
 @given(seeds)
 @settings(max_examples=10, deadline=None)
 def test_streamed_chunks_match_one_shot(seed):
-    # VERDICT r2 #9: incremental extend_trace + apply must equal the
+    # Incremental extend_trace + apply must equal the
     # whole-trace construction bit for bit, including identifier
     # interleavings that re-permute earlier slots.
     rng = random.Random(seed)

@@ -196,7 +196,8 @@ SECTIONS = (
 )
 
 # Directories the fallback linter walks (ruff takes its own config).
-LINT_TARGETS = ("crdt_tpu", "tools", "tests", "examples", "bench.py")
+LINT_TARGETS = ("crdt_tpu", "tools", "tests", "examples", "bench.py",
+                "chip_smoke.py")
 
 
 # ---- section: lint -------------------------------------------------------
@@ -703,12 +704,9 @@ def main(argv=None) -> int:
         os.environ.setdefault(
             "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
         )
-        os.environ.setdefault(
-            "JAX_COMPILATION_CACHE_DIR", os.path.join(ROOT, ".jax_cache")
-        )
-        os.environ.setdefault(
-            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.2"
-        )
+        from crdt_tpu.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
 
     from crdt_tpu.analysis.report import (
         Finding, SectionResult, errors, write_summary,
