@@ -374,127 +374,131 @@ class FanoutPlane:
         since the last push). ``_skip_versions`` is the broken-twin
         seam (``analysis.fixtures.fanout_skips_watermark_bucket``):
         production callers never pass it."""
-        top = self._top
-        st = self.sub_tenant[:top]
-        alive = st >= 0
-        safe_t = np.where(alive, st, 0)
-        if tenants is None:
-            dirty = self.dirt
-        else:
-            dirty = np.zeros(self.sb.n_tenants, bool)
-            dirty[np.atleast_1d(np.asarray(tenants, np.int64))] = True
-        lag = alive & (self.sub_ver[:top] < self.ver[safe_t])
-        sel = alive & (dirty[safe_t] | lag)
-        ids = np.where(sel)[0]
-        report = PushReport([], [], 0, 0, None)
-        if len(ids) == 0:
-            tel = self.annotate(tele.zeros()) if telemetry else None
-            return report._replace(telemetry=tel)
+        with tele.span("fanout.push"):
+            top = self._top
+            st = self.sub_tenant[:top]
+            alive = st >= 0
+            safe_t = np.where(alive, st, 0)
+            if tenants is None:
+                dirty = self.dirt
+            else:
+                dirty = np.zeros(self.sb.n_tenants, bool)
+                dirty[np.atleast_1d(np.asarray(tenants, np.int64))] = True
+            lag = alive & (self.sub_ver[:top] < self.ver[safe_t])
+            sel = alive & (dirty[safe_t] | lag)
+            ids = np.where(sel)[0]
+            report = PushReport([], [], 0, 0, None)
+            if len(ids) == 0:
+                tel = self.annotate(tele.zeros()) if telemetry else None
+                return report._replace(telemetry=tel)
 
-        # Residency + version bump for the dirty tenants being pushed
-        # (lag-only tenants keep their version: their stored newest
-        # base IS the live row — note_dirty is the change contract).
-        t_s = st[ids]
-        v_s = self.sub_ver[:top][ids]
-        pushed_tenants = np.unique(t_s)
+            # Residency + version bump for the dirty tenants being pushed
+            # (lag-only tenants keep their version: their stored newest
+            # base IS the live row — note_dirty is the change contract).
+            t_s = st[ids]
+            v_s = self.sub_ver[:top][ids]
+            pushed_tenants = np.unique(t_s)
 
-        # Residency is the cycle's working-set bound: a chunk's tenants
-        # must hold their lanes from the batched snapshot gather through
-        # the lane-indexed dispatch, so a push over MORE tenants than
-        # the lane pool proceeds in pool-sized chunks. Each chunk pins
-        # ONLY its own tenants against the restores' lane-pressure
-        # evictions (the ingest slab's ``restore(t, _exclude=placed)``
-        # discipline — without the pin a mid-cycle eviction hands an
-        # already-warmed cohort's lane to another tenant and its row
-        # ships as the wrong δ base); a later chunk is free to page an
-        # earlier chunk's lanes out, because that chunk already shipped.
-        pushes: List[CohortPush] = []
-        resyncs: List[CohortResync] = []
-        tel = None
-        n_cohorts = 0
-        n_subs = 0
-        n_resync_subs = 0
-        resync_bytes = 0.0
-        chunk_cap = max(self.sb.n_lanes, 1)
-        for lo in range(0, len(pushed_tenants), chunk_cap):
-            chunk = pushed_tenants[lo:lo + chunk_cap]
-            pinned = set(map(int, chunk))
-            for t in chunk:
-                self._ensure_resident(int(t), _exclude=pinned)
-            boundary("push.warm")
-            bumped = chunk[dirty[chunk]]
-            self._snapshot(bumped)
-            boundary("push.snapshot")
-            self.dirt[bumped] = False
+            # Residency is the cycle's working-set bound: a chunk's tenants
+            # must hold their lanes from the batched snapshot gather through
+            # the lane-indexed dispatch, so a push over MORE tenants than
+            # the lane pool proceeds in pool-sized chunks. Each chunk pins
+            # ONLY its own tenants against the restores' lane-pressure
+            # evictions (the ingest slab's ``restore(t, _exclude=placed)``
+            # discipline — without the pin a mid-cycle eviction hands an
+            # already-warmed cohort's lane to another tenant and its row
+            # ships as the wrong δ base); a later chunk is free to page an
+            # earlier chunk's lanes out, because that chunk already shipped.
+            pushes: List[CohortPush] = []
+            resyncs: List[CohortResync] = []
+            tel = None
+            n_cohorts = 0
+            n_subs = 0
+            n_resync_subs = 0
+            resync_bytes = 0.0
+            chunk_cap = max(self.sb.n_lanes, 1)
+            for lo in range(0, len(pushed_tenants), chunk_cap):
+                chunk = pushed_tenants[lo:lo + chunk_cap]
+                pinned = set(map(int, chunk))
+                with tele.span("fanout.warm"):
+                    for t in chunk:
+                        self._ensure_resident(int(t), _exclude=pinned)
+                boundary("push.warm")
+                bumped = chunk[dirty[chunk]]
+                with tele.span("fanout.snapshot"):
+                    self._snapshot(bumped)
+                boundary("push.snapshot")
+                self.dirt[bumped] = False
 
-            # Cohorts: subscribers sharing (tenant, acked version).
-            in_chunk = np.isin(t_s, chunk)
-            c_ids, c_t, c_v = ids[in_chunk], t_s[in_chunk], v_s[in_chunk]
-            code = c_t * (int(self.ver.max()) + 2) + c_v
-            order = np.argsort(code, kind="stable")
-            c_ids, c_t, c_v = c_ids[order], c_t[order], c_v[order]
-            _, starts, counts = np.unique(
-                code[order], return_index=True, return_counts=True
+                with tele.span("fanout.cohorts"):
+                    # Cohorts: subscribers sharing (tenant, acked version).
+                    in_chunk = np.isin(t_s, chunk)
+                    c_ids, c_t, c_v = ids[in_chunk], t_s[in_chunk], v_s[in_chunk]
+                    code = c_t * (int(self.ver.max()) + 2) + c_v
+                    order = np.argsort(code, kind="stable")
+                    c_ids, c_t, c_v = c_ids[order], c_t[order], c_v[order]
+                    _, starts, counts = np.unique(
+                        code[order], return_index=True, return_counts=True
+                    )
+
+                    wire_cohorts: List[tuple] = []
+                    for s, c in zip(starts, counts):
+                        t, v = int(c_t[s]), int(c_v[s])
+                        members = c_ids[s:s + c]
+                        target = int(self.ver[t])
+                        if v == target:
+                            continue  # already current (dirty push raced an ack)
+                        if v in _skip_versions:
+                            continue  # the broken-twin seam — never taken honestly
+                        base = self._base_row(t, v)
+                        if (target - v > self.window_cap) or base is None:
+                            crashpoints.hit(CP_RESYNC_PRE)
+                            from ..scaleout.bootstrap import bootstrap
+
+                            state, rep = bootstrap(
+                                self.kind, self.sb.row(t), base=base
+                            )
+                            resyncs.append(CohortResync(
+                                tenant=t, to_ver=target,
+                                state=jax.tree.map(np.asarray, state), report=rep,
+                                members=members,
+                            ))
+                            self.sub_pend[members] = target
+                            n_resync_subs += len(members)
+                            resync_bytes += rep.bytes_shipped * len(members)
+                            obs_trace.stamp("push", tenant=t, version=target)
+                            _rec.emit(
+                                "subscriber_resync", tenant=t,
+                                subscribers=len(members),
+                            )
+                        else:
+                            wire_cohorts.append((t, v, target, members, base))
+
+                chunk_pushes, chunk_tel = self._dispatch(wire_cohorts, telemetry)
+                pushes.extend(chunk_pushes)
+                n_cohorts += len(wire_cohorts)
+                n_subs += int(sum(len(m) for *_x, m, _b in wire_cohorts))
+                if chunk_tel is not None:
+                    tel = (
+                        chunk_tel if tel is None
+                        else tele.combine(tel, chunk_tel)
+                    )
+
+            self.resyncs_total += n_resync_subs
+            if telemetry:
+                tel = tele.zeros() if tel is None else tel
+                tel = self.annotate(tel._replace(
+                    resync_fallbacks=(
+                        tel.resync_fallbacks + jnp.uint32(n_resync_subs)
+                    ),
+                    bootstrap_bytes=(
+                        tel.bootstrap_bytes + jnp.float32(resync_bytes)
+                    ),
+                ))
+            return PushReport(
+                pushes=pushes, resyncs=resyncs, cohorts=n_cohorts,
+                subscribers=n_subs + n_resync_subs, telemetry=tel,
             )
-
-            wire_cohorts: List[tuple] = []
-            for s, c in zip(starts, counts):
-                t, v = int(c_t[s]), int(c_v[s])
-                members = c_ids[s:s + c]
-                target = int(self.ver[t])
-                if v == target:
-                    continue  # already current (dirty push raced an ack)
-                if v in _skip_versions:
-                    continue  # the broken-twin seam — never taken honestly
-                base = self._base_row(t, v)
-                if (target - v > self.window_cap) or base is None:
-                    crashpoints.hit(CP_RESYNC_PRE)
-                    from ..scaleout.bootstrap import bootstrap
-
-                    state, rep = bootstrap(
-                        self.kind, self.sb.row(t), base=base
-                    )
-                    resyncs.append(CohortResync(
-                        tenant=t, to_ver=target,
-                        state=jax.tree.map(np.asarray, state), report=rep,
-                        members=members,
-                    ))
-                    self.sub_pend[members] = target
-                    n_resync_subs += len(members)
-                    resync_bytes += rep.bytes_shipped * len(members)
-                    obs_trace.stamp("push", tenant=t, version=target)
-                    _rec.emit(
-                        "subscriber_resync", tenant=t,
-                        subscribers=len(members),
-                    )
-                else:
-                    wire_cohorts.append((t, v, target, members, base))
-
-            chunk_pushes, chunk_tel = self._dispatch(wire_cohorts, telemetry)
-            pushes.extend(chunk_pushes)
-            n_cohorts += len(wire_cohorts)
-            n_subs += int(sum(len(m) for *_x, m, _b in wire_cohorts))
-            if chunk_tel is not None:
-                tel = (
-                    chunk_tel if tel is None
-                    else tele.combine(tel, chunk_tel)
-                )
-
-        self.resyncs_total += n_resync_subs
-        if telemetry:
-            tel = tele.zeros() if tel is None else tel
-            tel = self.annotate(tel._replace(
-                resync_fallbacks=(
-                    tel.resync_fallbacks + jnp.uint32(n_resync_subs)
-                ),
-                bootstrap_bytes=(
-                    tel.bootstrap_bytes + jnp.float32(resync_bytes)
-                ),
-            ))
-        return PushReport(
-            pushes=pushes, resyncs=resyncs, cohorts=n_cohorts,
-            subscribers=n_subs + n_resync_subs, telemetry=tel,
-        )
 
     def _dispatch(self, cohorts, telemetry: bool):
         """Pack wire cohorts into ``dispatch_lanes``-wide
@@ -522,43 +526,48 @@ class FanoutPlane:
         )
         empty = self._empty_host()
         for dnum in range(n_disp):
-            idx = np.full(self.dispatch_lanes, -1, np.int32)
-            wts = np.zeros(self.dispatch_lanes, np.float32)
-            rows = [empty] * self.dispatch_lanes
-            slots: List[tuple] = []
-            for r in range(self.p):
-                chunk = per_rank[r][dnum * lpr_disp:(dnum + 1) * lpr_disp]
-                for j, (lane, (t, v, target, members, base)) in enumerate(
-                    chunk
-                ):
-                    dl = r * lpr_disp + j
-                    idx[dl] = lane % self.sb.lanes_per_rank
-                    wts[dl] = len(members)
-                    rows[dl] = base
-                    slots.append((dl, t, v, target, members))
-            bases_dev = jax.tree.map(
-                lambda *xs: jnp.asarray(np.stack(xs)), *rows
-            )
-            out = mesh_fanout_push(
-                self.sb.state, bases_dev, jnp.asarray(idx), self.mesh,
-                kind=self.kind, weights=jnp.asarray(wts),
-                telemetry=telemetry,
-            )
-            wire = jax.tree.map(np.asarray, out[0])
-            if telemetry:
-                t3 = out[2]
-                tel = t3 if tel is None else tele.combine(tel, t3)
-            for dl, t, v, target, members in slots:
-                pushes.append(CohortPush(
-                    tenant=t, base_ver=v, to_ver=target,
-                    wire=wire_lane(wire, dl), members=members,
-                ))
-                self.sub_pend[members] = target
-                obs_trace.stamp("push", tenant=t, version=target)
-            _rec.emit(
-                "fanout_push", cohorts=len(slots),
-                subscribers=int(wts.sum()),
-            )
+            with tele.span("fanout.dispatch", lanes=self.dispatch_lanes) as sp:
+                idx = np.full(self.dispatch_lanes, -1, np.int32)
+                wts = np.zeros(self.dispatch_lanes, np.float32)
+                rows = [empty] * self.dispatch_lanes
+                slots: List[tuple] = []
+                for r in range(self.p):
+                    chunk = per_rank[r][dnum * lpr_disp:(dnum + 1) * lpr_disp]
+                    for j, (lane, (t, v, target, members, base)) in enumerate(
+                        chunk
+                    ):
+                        dl = r * lpr_disp + j
+                        idx[dl] = lane % self.sb.lanes_per_rank
+                        wts[dl] = len(members)
+                        rows[dl] = base
+                        slots.append((dl, t, v, target, members))
+                sp.attrs["live"] = len(slots)
+                with tele.span("fanout.stack"):
+                    bases_dev = jax.tree.map(
+                        lambda *xs: jnp.asarray(np.stack(xs)), *rows
+                    )
+                out = mesh_fanout_push(
+                    self.sb.state, bases_dev, jnp.asarray(idx), self.mesh,
+                    kind=self.kind, weights=jnp.asarray(wts),
+                    telemetry=telemetry,
+                )
+                with tele.span("fanout.wire_fetch"):
+                    wire = jax.tree.map(np.asarray, out[0])
+                if telemetry:
+                    t3 = out[2]
+                    tel = t3 if tel is None else tele.combine(tel, t3)
+                with tele.span("fanout.deliver"):
+                    for dl, t, v, target, members in slots:
+                        pushes.append(CohortPush(
+                            tenant=t, base_ver=v, to_ver=target,
+                            wire=wire_lane(wire, dl), members=members,
+                        ))
+                        self.sub_pend[members] = target
+                        obs_trace.stamp("push", tenant=t, version=target)
+                    _rec.emit(
+                        "fanout_push", cohorts=len(slots),
+                        subscribers=int(wts.sum()),
+                    )
         return pushes, tel
 
     # ---- telemetry -------------------------------------------------------

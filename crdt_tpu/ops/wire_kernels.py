@@ -306,6 +306,7 @@ def wire_pack(
             jax.ShapeDtypeStruct((1, 8), jnp.int32),
         ],
         interpret=interpret,
+        name="wire_pack",
     )(*ins)
     words, keep, defer, cov, stats = outs
     return WirePackOut(

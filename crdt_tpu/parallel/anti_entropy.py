@@ -273,10 +273,6 @@ def mesh_fold(
     keep the [P, ...] layout, so there donation really aliases)."""
     from ..ops.pallas_kernels import fold_auto
 
-    orig = state
-    state = pad_replicas(state, mesh.shape[REPLICA_AXIS])
-    state = pad_elements(state, mesh.shape[ELEMENT_AXIS])
-
     def build():
         @partial(
             jax.shard_map,
@@ -322,25 +318,33 @@ def mesh_fold(
 
         return fold_tel_fn
 
-    metrics.count("anti_entropy.fold_rounds")
-    metrics.count(
-        "anti_entropy.merges", max(jax.tree.leaves(state)[0].shape[0] - 1, 0)
-    )
-    metrics.observe("anti_entropy.state_bytes", state_nbytes(state))
-    observe_depth("anti_entropy.orswot_fold", state)
-    t0 = time.perf_counter()
-    with metrics.time("anti_entropy.fold"):
-        out = _cached(
-            "orswot_fold", state, mesh,
-            build_tel if telemetry else build, local_fold, telemetry,
-        )(state)
-        jax.block_until_ready(out)  # time device work, not async dispatch
-    _consume(donate, state, orig)
-    if telemetry and tele.is_concrete(out[2]):
-        out = out[:2] + (tele.time_dispatch(
-            out[2], time.perf_counter() - t0
-        ),)
-        tele.record("orswot_fold", out[2])
+    with tele.span("ae.fold"):
+        with tele.span("ae.fold.prep"):
+            orig = state
+            state = pad_replicas(state, mesh.shape[REPLICA_AXIS])
+            state = pad_elements(state, mesh.shape[ELEMENT_AXIS])
+            metrics.count("anti_entropy.fold_rounds")
+            metrics.count(
+                "anti_entropy.merges",
+                max(jax.tree.leaves(state)[0].shape[0] - 1, 0),
+            )
+            metrics.observe("anti_entropy.state_bytes", state_nbytes(state))
+            observe_depth("anti_entropy.orswot_fold", state)
+            fn = _cached(
+                "orswot_fold", state, mesh,
+                build_tel if telemetry else build, local_fold, telemetry,
+            )
+        t0 = time.perf_counter()
+        with tele.span("ae.fold.dispatch"):
+            out = fn(state)
+        with tele.span("ae.fold.wait"):
+            jax.block_until_ready(out)  # time device work, not async dispatch
+        _consume(donate, state, orig)
+        if telemetry and tele.is_concrete(out[2]):
+            out = out[:2] + (tele.time_dispatch(
+                out[2], time.perf_counter() - t0
+            ),)
+            tele.record("orswot_fold", out[2])
     return out
 
 
@@ -587,7 +591,7 @@ def _mesh_gossip_lattice(
     metrics.observe("anti_entropy.state_bytes", state_nbytes(state))
     observe_depth(f"anti_entropy.{kind}", state)
     t0 = time.perf_counter()
-    with metrics.time(f"anti_entropy.{kind}"):
+    with tele.span(f"ae.{kind}"):
         out = _cached(
             kind, state, mesh, build,
             rounds, telemetry, stability, faults, *cache_extra,
@@ -797,7 +801,7 @@ def _mesh_fold_lattice(
     metrics.observe("anti_entropy.state_bytes", state_nbytes(state))
     observe_depth(f"anti_entropy.{kind}", state)
     t0 = time.perf_counter()
-    with metrics.time(f"anti_entropy.{kind}"):
+    with tele.span(f"ae.{kind}"):
         out = _cached(
             kind, state, mesh, build_tel if telemetry else build, telemetry
         )(state)
@@ -914,7 +918,7 @@ def mesh_fold_gset(present: jax.Array, mesh: Mesh) -> jax.Array:
 
     metrics.count("anti_entropy.gset_fold_rounds")
     metrics.observe("anti_entropy.state_bytes", float(present.nbytes))
-    with metrics.time("anti_entropy.gset_fold"):
+    with tele.span("ae.gset_fold"):
         out = _cached("gset_fold", present, mesh, build)(present)
         jax.block_until_ready(out)
     return out[:m]

@@ -1068,79 +1068,83 @@ def run_delta_ring(
 
         return gossip_fn
 
-    metrics.count(f"anti_entropy.{kind}_rounds", rounds)
-    metrics.observe("anti_entropy.state_bytes", state_nbytes(state))
-    if (wal is not None and wal.tail is None
-            and not isinstance(
-                jax.tree.leaves(state)[0], jax.core.Tracer
-            )):
-        # Seed the diff base BEFORE the jitted call — donation consumes
-        # the input buffers; attach takes a device copy. Skipped under
-        # an outer jit (tracers must never leak into the log's diff
-        # base) — the append below is skipped symmetrically.
-        wal.attach(state)
-    t0 = time.perf_counter()
-    with metrics.time(f"anti_entropy.{kind}"):
-        out = _cached(
-            kind, state, mesh, build, rounds, cap, telemetry, pipeline,
-            gated, faults, _ackwin.AckWindowKey() if acked else None,
-            # A fused=False run is the LEGACY program: mark its cache
-            # entry so the analysis gates keep reading the default
-            # (fused) trace — the FaultPlan/AckWindowKey discipline.
-            None if fused_on else _wire.WireKey(),
-            *cache_extra, donate_argnums=argnums,
-        )(state, dirty, fctx)
-        jax.block_until_ready(out)
-    if telemetry and tele.is_concrete(out[4]):
-        out = out[:4] + (tele.time_dispatch(
-            out[4], time.perf_counter() - t0
-        ),) + out[5:]
-    if donate:
-        # Free whatever the donation did not consume in place: the
-        # unaliasable fallback, and originals implicitly resharded onto
-        # the mesh (the executable then donated the committed copies).
-        from .anti_entropy import _consume
-
-        _consume(True, state, dirty)
-    # A faulted run's residue is forced >= 1 BY DESIGN (lost packets
-    # void the certificate) — the budget warning would misdiagnose it
-    # and burn the once-per-kind dedupe a genuine under-budget run
-    # needs; the gauge still records, the fault counters are the signal.
-    _warn_residue(kind, out, warn=not faulted)
-    if wal is not None and not isinstance(
-        jax.tree.leaves(out[0])[0], jax.core.Tracer
-    ):
-        # Host-side durability append (skipped under an outer jit —
-        # like tele.record, the caller then owns persistence).
-        b0, f0 = wal.bytes_appended, wal.fsyncs
-        with metrics.time("durability.wal_append"):
-            wal.append_state(wal_kind, out[0])
-            wal.mark_round()
+    with tele.span(f"ae.{kind}"):
+        with tele.span(f"ae.{kind}.prep"):
+            metrics.count(f"anti_entropy.{kind}_rounds", rounds)
+            metrics.observe("anti_entropy.state_bytes", state_nbytes(state))
+            if (wal is not None and wal.tail is None
+                    and not isinstance(
+                        jax.tree.leaves(state)[0], jax.core.Tracer
+                    )):
+                # Seed the diff base BEFORE the jitted call — donation consumes
+                # the input buffers; attach takes a device copy. Skipped under
+                # an outer jit (tracers must never leak into the log's diff
+                # base) — the append below is skipped symmetrically.
+                wal.attach(state)
+            fn = _cached(
+                kind, state, mesh, build, rounds, cap, telemetry, pipeline,
+                gated, faults, _ackwin.AckWindowKey() if acked else None,
+                # A fused=False run is the LEGACY program: mark its cache
+                # entry so the analysis gates keep reading the default
+                # (fused) trace — the FaultPlan/AckWindowKey discipline.
+                None if fused_on else _wire.WireKey(),
+                *cache_extra, donate_argnums=argnums,
+            )
+        t0 = time.perf_counter()
+        with tele.span(f"ae.{kind}.dispatch"):
+            out = fn(state, dirty, fctx)
+        with tele.span(f"ae.{kind}.wait"):
+            jax.block_until_ready(out)
         if telemetry and tele.is_concrete(out[4]):
-            out = out[:4] + (out[4]._replace(
-                wal_bytes=jnp.float32(wal.bytes_appended - b0),
-                wal_fsyncs=jnp.uint32(wal.fsyncs - f0),
+            out = out[:4] + (tele.time_dispatch(
+                out[4], time.perf_counter() - t0
             ),) + out[5:]
-    if acked:
-        metrics.count("delta_opt.ack_window_runs")
-        if telemetry and tele.is_concrete(out[4]):
-            skipped = int(out[4].bytes_acked_skipped)
-            metrics.count("delta_opt.acked_skipped", skipped)
-            metrics.count(f"delta_opt.acked_skipped.{kind}", skipped)
-    if fused_on:
-        metrics.count("wire.fused_runs")
-        if telemetry and tele.is_concrete(out[4]):
-            # The registry twins of the in-kernel packed-bytes counter
-            # (tools/telemetry_schema.json `wire_packed_bytes`).
-            pb = int(out[4].wire_packed_bytes)
-            metrics.count("wire.packed_bytes", pb)
-            metrics.count(f"wire.packed_bytes.{kind}", pb)
-    if telemetry and tele.is_concrete(out[4]):
-        tele.record(kind, out[4])
-    if faulted:
-        from .. import faults as flt
+        if donate:
+            # Free whatever the donation did not consume in place: the
+            # unaliasable fallback, and originals implicitly resharded onto
+            # the mesh (the executable then donated the committed copies).
+            from .anti_entropy import _consume
 
-        flt.record(out[-1])  # no-op under tracing, like tele.record
+            _consume(True, state, dirty)
+        # A faulted run's residue is forced >= 1 BY DESIGN (lost packets
+        # void the certificate) — the budget warning would misdiagnose it
+        # and burn the once-per-kind dedupe a genuine under-budget run
+        # needs; the gauge still records, the fault counters are the signal.
+        _warn_residue(kind, out, warn=not faulted)
+        if wal is not None and not isinstance(
+            jax.tree.leaves(out[0])[0], jax.core.Tracer
+        ):
+            # Host-side durability append (skipped under an outer jit —
+            # like tele.record, the caller then owns persistence).
+            b0, f0 = wal.bytes_appended, wal.fsyncs
+            with tele.span("durability.wal_append"):
+                wal.append_state(wal_kind, out[0])
+                wal.mark_round()
+            if telemetry and tele.is_concrete(out[4]):
+                out = out[:4] + (out[4]._replace(
+                    wal_bytes=jnp.float32(wal.bytes_appended - b0),
+                    wal_fsyncs=jnp.uint32(wal.fsyncs - f0),
+                ),) + out[5:]
+        if acked:
+            metrics.count("delta_opt.ack_window_runs")
+            if telemetry and tele.is_concrete(out[4]):
+                skipped = int(out[4].bytes_acked_skipped)
+                metrics.count("delta_opt.acked_skipped", skipped)
+                metrics.count(f"delta_opt.acked_skipped.{kind}", skipped)
+        if fused_on:
+            metrics.count("wire.fused_runs")
+            if telemetry and tele.is_concrete(out[4]):
+                # The registry twins of the in-kernel packed-bytes counter
+                # (tools/telemetry_schema.json `wire_packed_bytes`).
+                pb = int(out[4].wire_packed_bytes)
+                metrics.count("wire.packed_bytes", pb)
+                metrics.count(f"wire.packed_bytes.{kind}", pb)
+        if telemetry and tele.is_concrete(out[4]):
+            tele.record(kind, out[4])
+        if faulted:
+            from .. import faults as flt
+
+            flt.record(out[-1])  # no-op under tracing, like tele.record
     return out
 
 

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import telemetry as tele
 from ..ops import sparse_nest as nest
 from ..ops import sparse_orswot as sp
 from ..ops.sparse_nest import SparseNestState
@@ -198,7 +199,7 @@ def _sharded_fold(
     metrics.count(f"anti_entropy.{kind}_rounds")
     metrics.observe("anti_entropy.state_bytes", state_nbytes(states))
     observe_depth(f"anti_entropy.{kind}", states)
-    with metrics.time(f"anti_entropy.{kind}"):
+    with tele.span(f"ae.{kind}"):
         out = _cached(kind, states, mesh, build, *cache_extra)(states)
         jax.block_until_ready(out)
     return out

@@ -610,7 +610,7 @@ def _stream_fold(
         ) from exc
 
     observe_depth(f"stream.{plan.kind}", first if first is not None else acc)
-    with metrics.time(f"stream.{plan.kind}"):
+    with tele.span(f"stream.{plan.kind}"):
         while staged is not None:
             staged_bytes += tele.shipped_bytes(staged)
             if widen_policy is None:

@@ -171,62 +171,68 @@ class ServeLoop:
         (round N — one step of latency behind the submit stream, the
         price of the overlap; :meth:`flush_inflight` settles the tail).
         """
-        self.steps += 1
-        pin = ()
-        if self.inflight is not None:
-            b0 = self.inflight[0]
-            pin = [t for t, _ in b0.taken]
-        built = self.q._assemble(pin=pin)
-        seq = None
-        if built.applied:
-            try:
-                seq = self.q._log(built)
-            except BaseException as exc:
-                self.q._unwind(built, exc)
-                raise
-        report = tel = None
-        if self.inflight is not None:
-            n_built, n_pending, n_seq = self.inflight
-            if not n_pending.ready():
-                # Host-side assembly + WAL commit genuinely hid device
-                # time this round — the quantity the bench headlines.
-                self.overlap_hits += 1
-                metrics.count("serve.loop.overlap_hit")
-            self.inflight = None
+        with tele.span("serve.step"):
+            self.steps += 1
+            pin = ()
+            if self.inflight is not None:
+                b0 = self.inflight[0]
+                pin = [t for t, _ in b0.taken]
+            with tele.span("serve.assemble"):
+                built = self.q._assemble(pin=pin)
+            seq = None
+            if built.applied:
+                try:
+                    with tele.span("serve.wal_commit"):
+                        seq = self.q._log(built)
+                except BaseException as exc:
+                    self.q._unwind(built, exc)
+                    raise
+            report = tel = None
+            if self.inflight is not None:
+                n_built, n_pending, n_seq = self.inflight
+                if not n_pending.ready():
+                    # Host-side assembly + WAL commit genuinely hid device
+                    # time this round — the quantity the bench headlines.
+                    self.overlap_hits += 1
+                    metrics.count("serve.loop.overlap_hit")
+                self.inflight = None
 
-            def _requeue_next(exc, _b=built, _s=seq):
-                if _b.applied:
-                    self.q._unwind(
-                        _b, RuntimeError("pipeline unwind"),
-                        requeue_seq=_s,
+                def _requeue_next(exc, _b=built, _s=seq):
+                    if _b.applied:
+                        self.q._unwind(
+                            _b, RuntimeError("pipeline unwind"),
+                            requeue_seq=_s,
+                        )
+
+                with tele.span("serve.finish"):
+                    report, tel = self.q._finish(
+                        n_built, n_pending, n_seq, telemetry=telemetry,
+                        on_fail=_requeue_next,
                     )
-
-            report, tel = self.q._finish(
-                n_built, n_pending, n_seq, telemetry=telemetry,
-                on_fail=_requeue_next,
-            )
-        # Background persists run in the settled window between
-        # finish(N) and issue(N+1): no dispatch is in flight, so a
-        # row read here can neither block on an unfinished scatter
-        # nor capture an overflowed value a rollback would retract.
-        boundary("persist.window")
-        if self.persister is not None:
-            if self.persist_ahead:
-                self.persister.enqueue_cold(
-                    self.persist_ahead,
-                    exclude=[t for t, _ in built.taken],
-                )
-            self.persister.drain()
-        if built.applied:
-            try:
-                pend = self.q._issue(built, telemetry=telemetry)
-            except BaseException as exc:
-                self.q._unwind(built, exc, requeue_seq=seq)
-                raise
-            self.inflight = (built, pend, seq)
-        if tel is not None:
-            tel = self.annotate(tel)
-        return report, tel
+            # Background persists run in the settled window between
+            # finish(N) and issue(N+1): no dispatch is in flight, so a
+            # row read here can neither block on an unfinished scatter
+            # nor capture an overflowed value a rollback would retract.
+            boundary("persist.window")
+            if self.persister is not None:
+                with tele.span("serve.persist_drain"):
+                    if self.persist_ahead:
+                        self.persister.enqueue_cold(
+                            self.persist_ahead,
+                            exclude=[t for t, _ in built.taken],
+                        )
+                    self.persister.drain()
+            if built.applied:
+                try:
+                    with tele.span("serve.issue"):
+                        pend = self.q._issue(built, telemetry=telemetry)
+                except BaseException as exc:
+                    self.q._unwind(built, exc, requeue_seq=seq)
+                    raise
+                self.inflight = (built, pend, seq)
+            if tel is not None:
+                tel = self.annotate(tel)
+            return report, tel
 
     def flush_inflight(self, *, telemetry: bool = False):
         """Finish the in-flight dispatch without assembling a new slab
@@ -235,9 +241,10 @@ class ServeLoop:
             return None, None
         n_built, n_pending, n_seq = self.inflight
         self.inflight = None
-        report, tel = self.q._finish(
-            n_built, n_pending, n_seq, telemetry=telemetry,
-        )
+        with tele.span("serve.finish"):
+            report, tel = self.q._finish(
+                n_built, n_pending, n_seq, telemetry=telemetry,
+            )
         if tel is not None:
             tel = self.annotate(tel)
         return report, tel

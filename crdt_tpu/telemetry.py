@@ -184,26 +184,30 @@ costs one word of output per field (plus one 32-lane counter plane per
 histogram) and no extra collectives beyond one psum/pmax fusion group.
 
 Span tracing (:func:`span`) is the host-side half: a context manager
-that emits structured JSONL trace events (``configure_tracing`` points
-them at a file; ``drain_events`` empties the in-memory ring) and nests
-``jax.named_scope`` + ``jax.profiler.TraceAnnotation`` so the same
-span names appear in XProf device timelines. Exporting both worlds —
+around a layer boundary that records only while someone listens — a
+profiler session, a JSONL path (``configure_tracing``) or a flight
+recorder — and is one flag check otherwise. A recorded span is a
+``jax.profiler.TraceAnnotation`` on the device trace's clock, a
+structured trace event (``drain_events`` empties the in-memory ring)
+and a per-name aggregate (:func:`span_stats`). Exporting both worlds —
 registry snapshots, Telemetry pytrees, spans — to Prometheus text and
 JSONL lives in :mod:`crdt_tpu.exporter`.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
+import numbers
 import threading
 import time
+from collections import deque
 from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from .obs import hist as obs_hist
+from .obs import recorder as _rec
 from .utils.metrics import metrics
 
 
@@ -804,8 +808,6 @@ def record(kind: str, tel: Telemetry) -> None:
         s = obs_hist.summary(hd)
         for q in ("p50", "p95", "p99"):
             metrics.observe(f"{base}.{q}", s[q])
-    from .obs import recorder as _rec
-
     if _rec.get_recorder() is not None:
         # Emit FIRST, advance AFTER: the telemetry drain is the last
         # event of its dispatch, so everything the dispatch emitted
@@ -822,12 +824,15 @@ _trace_lock = threading.Lock()
 _trace_events: list = []
 _trace_path: Optional[str] = None
 _MAX_BUFFERED_EVENTS = 65536
+_SPAN_RING = 4096   # per-name call durations kept by the aggregate
 _local = threading.local()
+_profiling = jax.profiler.TraceAnnotation.is_enabled
+_stats: Dict[str, Dict[str, Any]] = {}
 
 
 def configure_tracing(path: Optional[str]) -> None:
-    """Point span JSONL emission at ``path`` (append mode; None = keep
-    events only in the in-memory ring for :func:`drain_events`)."""
+    """Point span JSONL emission at ``path`` (append mode). A path also
+    switches span recording on; ``None`` switches that reason off."""
     global _trace_path
     with _trace_lock:
         _trace_path = path
@@ -840,12 +845,48 @@ def drain_events() -> list:
     return out
 
 
+def span_stats() -> Dict[str, Dict[str, Any]]:
+    """Per span name, what the recorded spans add up to: ``n`` calls,
+    ``total_s``, ``self_s`` (total less the time child spans cover),
+    ``attrs`` (the sum of each numeric attribute) and ``durations_s``
+    (the newest 4,096 calls, oldest first)."""
+    with _trace_lock:
+        return {
+            name: {
+                "n": a["n"], "total_s": a["total_s"], "self_s": a["self_s"],
+                "attrs": dict(a["attrs"]), "durations_s": list(a["durations_s"]),
+            }
+            for name, a in _stats.items()
+        }
+
+
+def reset_span_stats() -> None:
+    """Clear the :func:`span_stats` aggregate."""
+    with _trace_lock:
+        _stats.clear()
+
+
+def _aggregate(name: str, dur: float, self_s: float, attrs) -> None:
+    with _trace_lock:
+        a = _stats.get(name)
+        if a is None:
+            a = _stats[name] = {"n": 0, "total_s": 0.0, "self_s": 0.0,
+                                "attrs": {},
+                                "durations_s": deque(maxlen=_SPAN_RING)}
+        a["n"] += 1
+        a["total_s"] += dur
+        a["self_s"] += self_s
+        a["durations_s"].append(dur)
+        sums = a["attrs"]
+        for k, v in attrs.items():
+            if isinstance(v, numbers.Real) and not isinstance(v, bool):
+                sums[k] = sums.get(k, 0) + v
+
+
 def _emit(event: Dict[str, Any]) -> None:
     # Stamp the flight recorder's (generation, round, rank) correlation
     # key when one is installed, so spans and flight events line up on
     # one timeline (obs/recorder.py module docstring).
-    from .obs import recorder as _rec
-
     k = _rec.current_key()
     if k is not None:
         event.setdefault("gen", k[0])
@@ -866,54 +907,79 @@ def _emit(event: Dict[str, Any]) -> None:
             pass
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs):
-    """A named span: structured JSONL event on exit (wall-clock start,
-    duration, attrs, parent span) AND the same name nested into
-    ``jax.named_scope`` + ``jax.profiler.TraceAnnotation``, so host
-    spans line up with XProf device timelines. Also feeds the registry
-    timer histogram (``<name>_seconds`` gauge) so snapshot-only
-    consumers see span durations too. Attrs must be JSON-serializable.
+class _Span:
+    __slots__ = ("name", "attrs", "_on", "_ann", "_parent", "_child",
+                 "_wall", "_t0")
 
-    When a flight recorder is installed (``crdt_tpu.obs.install`` —
-    obs/recorder.py), every span event additionally carries the
-    recorder's monotonic ``(generation, round, rank)`` correlation key
-    as ``gen``/``round``/``rank`` fields, so spans interleave with the
-    recorder's per-round subsystem events (fault draws, membership
-    decisions, WAL watermarks, scale-out votes) on ONE timeline in a
-    ``FlightRecorder.dump()`` postmortem artifact and in
-    ``tools/obs_report.py``'s rendering of it.
-    """
-    stack = getattr(_local, "stack", None)
-    if stack is None:
-        stack = _local.stack = []
-    parent = stack[-1] if stack else None
-    stack.append(name)
-    t_wall = time.time()
-    t0 = time.perf_counter()
-    try:
-        with contextlib.ExitStack() as es:
-            # The registry timer owns the `<name>_seconds` gauge (same
-            # shape as every other metrics.time site); the local clock
-            # below only feeds the trace event.
-            es.enter_context(metrics.time(name))
-            es.enter_context(jax.named_scope(name))
-            try:
-                es.enter_context(jax.profiler.TraceAnnotation(name))
-            except Exception:
-                pass  # profiler backend unavailable — host event still fires
-            yield
-    finally:
-        stack.pop()
-        dur = time.perf_counter() - t0
+    def __init__(self, name: str, attrs: Dict[str, Any]):
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self):
+        if _trace_path is None and _rec.get_recorder() is None and not _profiling():
+            self._on = False
+            return self
+        self._on = True
+        stack = getattr(_local, "stack", None)
+        if stack is None:
+            stack = _local.stack = []
+        self._parent = stack[-1] if stack else None
+        stack.append(self)
+        self._child = 0.0
+        try:
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        except Exception:
+            self._ann = None  # profiler backend unavailable: the rest still records
+        self._wall = time.time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if not self._on:
+            return False
+        dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _local.stack.pop()
+        parent = self._parent
+        if parent is not None:
+            parent._child += dur
+        _aggregate(self.name, dur, dur - self._child, self.attrs)
+        metrics.observe(f"{self.name}_seconds", dur)
         _emit({
             "record": "span",
-            "name": name,
-            "ts": t_wall,
+            "name": self.name,
+            "ts": self._wall,
             "dur_s": dur,
-            "parent": parent,
-            "attrs": attrs,
+            "parent": None if parent is None else parent.name,
+            "attrs": self.attrs,
         })
+        return False
+
+
+def span(name: str, **attrs) -> _Span:
+    """A named span around a layer boundary. It records only while a
+    profiler session runs (``TraceAnnotation.is_enabled()``), a JSONL
+    path is configured (:func:`configure_tracing`) or a flight recorder
+    is installed (``crdt_tpu.obs.install``); otherwise entering it is
+    one flag check. A recorded span is:
+
+    - a ``jax.profiler.TraceAnnotation`` of ``name``: the span on the
+      device trace's clock, on the host thread's line;
+    - a structured trace event on exit (wall-clock start, duration,
+      attrs, parent span), stamped with the flight recorder's
+      ``(generation, round, rank)`` key as ``gen``/``round``/``rank``
+      when one is installed, so spans interleave with the recorder's
+      per-round events in a ``FlightRecorder.dump()`` artifact;
+    - the registry gauge ``<name>_seconds``;
+    - a sample of the per-name aggregate :func:`span_stats`.
+
+    Attrs must be JSON-serializable; the context manager's value is
+    the span, whose ``attrs`` dict may be filled in before exit.
+    Program span names never start with ``span.``: that prefix is the
+    benchmark's own."""
+    return _Span(name, attrs)
 
 
 def reset_residue_warnings() -> None:
@@ -931,6 +997,6 @@ __all__ = [
     "counter_increments",
     "device_depth", "device_pressure", "drain_events",
     "generic_slots_changed", "is_concrete", "packet_useful_bytes",
-    "record", "reset_residue_warnings", "shipped_bytes",
-    "span", "specs", "time_dispatch", "to_dict", "zeros",
+    "record", "reset_residue_warnings", "reset_span_stats", "shipped_bytes",
+    "span", "span_stats", "specs", "time_dispatch", "to_dict", "zeros",
 ]

@@ -451,6 +451,7 @@ def test_prometheus_histogram_exposition_conformance():
 
 def test_jsonl_drain_idempotent_under_concurrent_spans(tmp_path):
     tele.drain_events()
+    obs.install(obs.FlightRecorder(capacity=8))  # a listener: spans record
     n_threads, per_thread = 4, 50
     stop = threading.Event()
 

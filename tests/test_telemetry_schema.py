@@ -17,16 +17,21 @@ from crdt_tpu import exporter, telemetry  # noqa: E402
 from crdt_tpu.utils.metrics import Metrics, metrics  # noqa: E402
 
 
-def _activity():
+def _activity(tmp_path):
     metrics.count("schema_test.counter", 5)
     metrics.observe("schema_test.gauge", 2.5)
-    with telemetry.span("schema_test.outer", shape="4x8"):
-        with telemetry.span("schema_test.inner"):
-            pass
+    # A JSONL path is a listener: spans record only while one exists.
+    telemetry.configure_tracing(str(tmp_path / "activity_spans.jsonl"))
+    try:
+        with telemetry.span("schema_test.outer", shape="4x8"):
+            with telemetry.span("schema_test.inner"):
+                pass
+    finally:
+        telemetry.configure_tracing(None)
 
 
 def test_drain_jsonl_validates_against_committed_schema(tmp_path):
-    _activity()
+    _activity(tmp_path)
     tel = telemetry.zeros()
     path = str(tmp_path / "metrics.jsonl")
     n = exporter.drain_jsonl(path, telemetry={"orswot_gossip": tel})
@@ -39,8 +44,8 @@ def test_drain_jsonl_validates_against_committed_schema(tmp_path):
     assert {"snapshot", "telemetry", "span"} <= set(kinds)
 
 
-def test_registry_snapshot_validates():
-    _activity()
+def test_registry_snapshot_validates(tmp_path):
+    _activity(tmp_path)
     assert cts.validate_snapshot(metrics.snapshot()) == []
 
 
@@ -170,11 +175,15 @@ def test_span_survives_unserializable_attrs(tmp_path):
     assert cts.validate_jsonl(out) == []
 
 
-def test_span_events_nest_and_drain():
+def test_span_events_nest_and_drain(tmp_path):
     telemetry.drain_events()  # clear
-    with telemetry.span("outer_span", a=1):
-        with telemetry.span("inner_span"):
-            pass
+    telemetry.configure_tracing(str(tmp_path / "spans.jsonl"))
+    try:
+        with telemetry.span("outer_span", a=1):
+            with telemetry.span("inner_span"):
+                pass
+    finally:
+        telemetry.configure_tracing(None)
     events = telemetry.drain_events()
     assert [e["name"] for e in events] == ["inner_span", "outer_span"]
     inner, outer = events
