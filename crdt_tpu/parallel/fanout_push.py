@@ -49,6 +49,7 @@ from ..ops.fanout_kernels import (
     cohort_push_bytes,
     cohort_wire_encode,
 )
+from ..utils.metrics import metrics
 from .anti_entropy import _cached
 from .mesh import REPLICA_AXIS
 
@@ -68,11 +69,12 @@ def _validate(state, bases, idx, p: int) -> None:
 
 
 def _local_push(kind: str, state, bases, idx):
-    """The per-device core (also traced under ``jax.eval_shape`` to
-    derive the wire's out_specs): gather → vmapped decompose vs the
-    cohort bases → one fused wire-pack over the local batch → the
-    per-cohort byte price. Empty lanes (``idx < 0``) zero out of the
-    lane mask, the wire, and the price."""
+    """The per-device core (also traced under ``jax.eval_shape``, once
+    per compiled program, to derive the wire's out_specs): gather →
+    vmapped decompose vs the cohort bases → one fused wire-pack over
+    the local batch → the per-cohort byte price. Empty lanes
+    (``idx < 0``) zero out of the lane mask, the wire, and the
+    price."""
     from ..analysis.registry import get_decomposer
     from ..delta_opt.decompose import Decomposition
 
@@ -122,18 +124,22 @@ def mesh_fanout_push(
         else jnp.asarray(weights, jnp.float32)
     )
 
-    # The wire's pytree structure (for out_specs): trace the core once
-    # abstractly — scalar leaves (nnz/chk) replicate, batched leaves
-    # shard over the replica axis like the lanes they price.
-    wire_struct, _ = jax.eval_shape(
-        lambda s, b, i: _local_push(kind, s, b, i), state, bases, idx
-    )
     row_spec = P(REPLICA_AXIS)
-    wire_spec = jax.tree.map(
-        lambda s: row_spec if s.ndim else P(), wire_struct
-    )
 
     def build():
+        # The wire's pytree structure (for out_specs): trace the core
+        # abstractly once per compiled program (the cache key holds
+        # every shape and dtype the trace reads) — scalar leaves
+        # (nnz/chk) replicate, batched leaves shard over the replica
+        # axis like the lanes they price.
+        metrics.count("fanout_push.spec_builds")
+        wire_struct, _ = jax.eval_shape(
+            lambda s, b, i: _local_push(kind, s, b, i), state, bases, idx
+        )
+        wire_spec = jax.tree.map(
+            lambda s: row_spec if s.ndim else P(), wire_struct
+        )
+
         def body(state, bases, idx, wts):
             wire, pb = _local_push(kind, state, bases, idx)
             wire = wire._replace(

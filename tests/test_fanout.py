@@ -56,8 +56,10 @@ from crdt_tpu.ops.fanout_kernels import (
     cohort_wire_decode,
     cohort_wire_encode,
 )
-from crdt_tpu.parallel import make_mesh, mesh_fanout_push
+from crdt_tpu.parallel import anti_entropy, make_mesh, mesh_fanout_push
+from crdt_tpu.parallel import fanout_push as fanout_push_mod
 from crdt_tpu.serve import Evictor, IngestQueue, Superblock
+from crdt_tpu.utils.metrics import metrics
 
 CAPS = dict(n_elems=8, n_actors=2, deferred_cap=2)
 
@@ -548,3 +550,111 @@ def test_mesh_fanout_push_empty_lanes_are_free():
     pb = np.asarray(pb)
     assert pb[0] > 0 and pb[1] == 0
     assert not bool(jnp.any(wire.valid[1]))
+
+
+# ---- 6. the dispatch's wire specs, once per compiled program -------------
+
+def _push_args(caps=CAPS, lanes=2, tenants=2):
+    """A superblock with one live row, ``lanes`` base rows, and a
+    dispatch that reads the live row in lane 0 (the rest empty)."""
+    tk = sb_ops.tenant_kind("orswot")
+    state = tk.empty(**caps, batch=(tenants,))
+    row, _ = tk.apply_add(
+        tk.empty(**caps), jnp.int32(0), jnp.uint32(1),
+        jnp.asarray(_mask(0, 3, e=caps["n_elems"])),
+    )
+    state = sb_ops.write_rows(
+        state, jnp.asarray([0], jnp.int32),
+        jax.tree.map(lambda x: x[None], row),
+    )
+    bases = tk.empty(**caps, batch=(lanes,))
+    idx = np.full(lanes, -1, np.int32)
+    idx[0] = 0
+    wts = np.zeros(lanes, np.float32)
+    wts[0] = 3.0
+    return state, bases, jnp.asarray(idx), jnp.asarray(wts)
+
+
+@pytest.fixture
+def push_traces(monkeypatch):
+    """Against an empty program cache, count the traces of
+    ``mesh_fanout_push``'s per-device core (abstract and compiled
+    alike) and the ``fanout_push.spec_builds`` counter."""
+    monkeypatch.setattr(anti_entropy, "_FN_CACHE", {})
+    traced = []
+    core = fanout_push_mod._local_push
+
+    def counting(*a, **k):
+        traced.append(a[0])
+        return core(*a, **k)
+
+    monkeypatch.setattr(fanout_push_mod, "_local_push", counting)
+
+    def counts():
+        builds = metrics.snapshot()["counters"].get(
+            "fanout_push.spec_builds", 0
+        )
+        return len(traced), builds
+
+    return counts
+
+
+@pytest.mark.parametrize("telemetry", [False, True])
+def test_fanout_push_repeat_call_traces_nothing(push_traces, telemetry):
+    """The second call at one signature reuses the compiled program and
+    its wire specs: no abstract trace of the core, no spec build."""
+    mesh = make_mesh(1, 1)
+    state, bases, idx, wts = _push_args()
+    t0, b0 = push_traces()
+    mesh_fanout_push(state, bases, idx, mesh, weights=wts,
+                     telemetry=telemetry)
+    t1, b1 = push_traces()
+    assert b1 == b0 + 1 and t1 > t0
+    for _ in range(2):
+        mesh_fanout_push(state, bases, idx, mesh, weights=wts,
+                         telemetry=telemetry)
+    assert push_traces() == (t1, b1)
+
+
+@pytest.mark.parametrize("change", ["dispatch_width", "widened_caps"])
+def test_fanout_push_new_signature_builds_once(push_traces, change):
+    """A new dispatch width, or caps widened under the superblock, is a
+    new program: its specs are built exactly once more, with the same
+    tracing as the first build, and then reused."""
+    mesh = make_mesh(1, 1)
+    t0, b0 = push_traces()
+    state, bases, idx, wts = _push_args()
+    mesh_fanout_push(state, bases, idx, mesh, weights=wts)
+    t1, b1 = push_traces()
+    if change == "dispatch_width":
+        args = _push_args(lanes=4)
+    else:
+        args = _push_args(caps=dict(CAPS, n_elems=16))
+    for _ in range(2):
+        mesh_fanout_push(*args[:3], mesh, weights=args[3])
+        assert push_traces() == (t1 + (t1 - t0), b1 + 1)
+
+
+@pytest.mark.parametrize("telemetry", [False, True])
+def test_fanout_push_cached_call_matches_a_fresh_build(push_traces,
+                                                      telemetry):
+    """A call served from the program cache ships the same wire and
+    byte prices, bit for bit, as one made after the cache is cleared."""
+    mesh = make_mesh(1, 1)
+    state, bases, idx, wts = _push_args(lanes=4)
+
+    def call():
+        out = mesh_fanout_push(state, bases, idx, mesh, weights=wts,
+                               telemetry=telemetry)
+        return jax.tree.map(np.asarray, (out[0], out[1]))
+
+    _, b0 = push_traces()
+    call()
+    cached = call()
+    anti_entropy._FN_CACHE.clear()
+    fresh = call()
+    assert push_traces()[1] == b0 + 2
+    for x, y in zip(jax.tree.leaves(cached), jax.tree.leaves(fresh)):
+        assert x.dtype == y.dtype and x.shape == y.shape
+        assert x.tobytes() == y.tobytes()
+    assert np.asarray(cached[1])[0] > 0
