@@ -489,7 +489,7 @@ def _fold_fused_level_jit(
 
 def _decode_wide(child, a: int):
     """Slot table ``MVRegState [R, K, S…]`` → K-minor dense per-(actor,
-    key) arrays (wctr [R, A, K], val1 [R, A, K], clk [R, A, A, K]).
+    key) witness counters ``wctr [R, A, K]`` (0 = no live sibling).
 
     Sound because a key holds at most one live sibling per actor: a
     later write by the same actor carries a clock ≥ its earlier write's
@@ -507,63 +507,71 @@ def _decode_wide(child, a: int):
     r, k, s = child.wact.shape
     act_t = jnp.swapaxes(child.wact, -1, -2)    # [R, S, K]
     wctr_t = jnp.swapaxes(child.wctr, -1, -2)
-    val_t = jnp.swapaxes(child.val, -1, -2)
     live_t = jnp.swapaxes(child.valid, -1, -2)
-    clk_t = jnp.transpose(child.clk, (0, 2, 3, 1))  # [R, S, A, K]
     ids = jnp.arange(a, dtype=child.wact.dtype)
     wctr = jnp.zeros((r, a, k), child.wctr.dtype)
-    val1 = jnp.zeros((r, a, k), jnp.uint32)
-    clk = jnp.zeros((r, a, a, k), child.clk.dtype)
     for si in range(s):
         own = (act_t[:, si, None, :] == ids[None, :, None]) & live_t[:, si, None, :]
         wctr = _umax(wctr, jnp.where(own, wctr_t[:, si, None, :], 0))
-        # val ids are ≥ 0; shift by one so "absent" is distinguishable.
-        val1 = _umax(
-            val1,
-            jnp.where(own, val_t[:, si, None, :].astype(jnp.uint32) + 1, 0),
-        )
-        clk = _umax(clk, jnp.where(own[:, :, None, :], clk_t[:, si, None, :, :], 0))
-    return wctr, val1, clk
+    return wctr
 
 
-def _wide_to_slots(wctr, val1, clk, s: int):
-    """K-minor dense cells (unbatched: wctr [A, K], val1 [A, K],
-    clk [A, A, K]) → canonical slot table fitted to S slots, API shapes
-    ``[K, S(, A)]``. Every large intermediate stays K-minor; only the
-    final (output) transposes leave the lane-friendly layout."""
-    from .mvreg import MVRegState
-
-    a, k = wctr.shape
-    present = wctr > 0
-    # Canonical slot order (ops/map._canon_child): valid first, then by
-    # actor (unique per key, so no further tiebreak needed).
+def _slot_order(folded_w, s: int):
+    """Folded counters ``[A, K]`` → the canonical slot table's dots, fitted
+    to S slots: per key the live cells, valid first and then by actor
+    (unique per key, so no further tiebreak; ops/map._canon_child), as
+    ``(wact [S, K], wctr [S, K], valid [S, K])``. Truncating when A > S
+    keeps the lowest actors, as the tree join's canonical compaction
+    does; dead slots are zero."""
+    a, k = folded_w.shape
+    present = folded_w > 0
     order = jnp.argsort(~present, axis=0, stable=True)  # [A, K] actor ids
-    take = lambda x: jnp.take_along_axis(x, order, axis=0)
-    valid = take(present)
-    acts = jnp.broadcast_to(jnp.arange(a, dtype=jnp.int32)[:, None], (a, k))
-    wact_s = jnp.where(valid, take(acts), 0)
-    wctr_s = jnp.where(valid, take(wctr), 0)
-    val_s = jnp.where(valid, take(val1).astype(jnp.int32) - 1, 0)
-    clk_s = jnp.where(
-        valid[:, None, :],
-        jnp.take_along_axis(clk, order[:, None, :], axis=0),
-        0,
-    )
+    valid = jnp.take_along_axis(present, order, axis=0)
+    wact = jnp.where(valid, order.astype(jnp.int32), 0)
+    wctr = jnp.where(valid, jnp.take_along_axis(folded_w, order, axis=0), 0)
+    if a >= s:
+        return wact[:s], wctr[:s], valid[:s]
+    pad = lambda x: jnp.pad(x, ((0, s - a), (0, 0)))  # noqa: E731
+    return pad(wact), pad(wctr), pad(valid)
 
-    # Back to the slot capacity: truncate (A > S) or zero-pad (A < S) —
-    # canonical form keeps dead slots zeroed either way.
-    def fit(x):
-        if a >= s:
-            return x[:s]
-        return jnp.pad(x, [(0, s - a)] + [(0, 0)] * (x.ndim - 1))
 
-    return MVRegState(
-        wact=fit(wact_s).T,
-        wctr=fit(wctr_s).T,
-        clk=jnp.transpose(fit(clk_s), (2, 0, 1)),
-        val=fit(val_s).T,
-        valid=fit(valid).T,
-    )
+def _payload_of(child, wact, wctr):
+    """The payload of the selected dots (``wact``/``wctr [S, K]``, counter
+    0 = none) among a block of slot tables ``MVRegState [N, K, S…]``:
+    ``(val + 1 [S, K], clk [S, A, K])``, 0 where no row holds the dot.
+    Every row that holds a dot holds the same payload for it (one dot is
+    one write), so the max over rows is that payload. One reduction over
+    (rows, slots) per selected slot: XLA streams each from the slot
+    planes without materialising a wide temporary."""
+    act_t = jnp.swapaxes(child.wact, -1, -2)    # [N, S, K]
+    wctr_t = jnp.swapaxes(child.wctr, -1, -2)
+    # val ids are ≥ 0; shift by one so "absent" is distinguishable.
+    val_t = jnp.swapaxes(child.val, -1, -2).astype(jnp.uint32) + 1
+    live_t = jnp.swapaxes(child.valid, -1, -2)
+    clk_t = jnp.transpose(child.clk, (0, 2, 3, 1))  # [N, S, A, K]
+    vals, clks = [], []
+    for j in range(wact.shape[0]):
+        hit = live_t & (wctr[j] > 0) & (act_t == wact[j]) & (wctr_t == wctr[j])
+        vals.append(jnp.max(jnp.where(hit, val_t, 0), axis=(0, 1)))
+        clks.append(jnp.max(jnp.where(hit[:, :, None, :], clk_t, 0), axis=(0, 1)))
+    return jnp.stack(vals), jnp.stack(clks)
+
+
+# HBM budget for the replica chunk a map fold decodes at once: at config
+# 4's width (A = 8, K = 2^20) the wide counters are 32 MiB a replica, so
+# a chunk is 32 replicas and a 64-replica fold holds half its batch in
+# the wide form at a time, whatever R is.
+_MAP_CHUNK_BUDGET = 1 << 30
+
+
+def _map_chunk_rows(r: int, a: int, k: int) -> int:
+    """Replicas a map fold decodes per chunk: the most whose wide
+    counters ``[rows, A, K]``, padded to the (8, 128) tiles, fit
+    ``_MAP_CHUNK_BUDGET``, rounded down to a power of two (the kernel's
+    halving tree then takes a whole chunk unpadded), and at most R."""
+    row = -(-a // 8) * 8 * (-(-k // 128) * 128) * 4
+    rows = max(1, min(r, _MAP_CHUNK_BUDGET // row))
+    return 1 << (rows.bit_length() - 1)
 
 
 def fold_fused_map(
@@ -572,77 +580,132 @@ def fold_fused_map(
     r_chunk: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> Tuple[object, jax.Array]:
-    """Fused fold for ``Map<K, MVReg>`` (``ops.map.MapState``) — the
-    config-4 hot loop in one streamed HBM pass.
+    """Fused fold for ``Map<K, MVReg>`` (``ops.map.MapState``), the
+    config-4 hot loop, in replica chunks of ``_map_chunk_rows``.
 
-    The slot tables convert to a dense per-(key, actor) witness-counter
-    slab (``_decode_wide``), whose replica fold is the cell-granular
-    dot rule — the Pallas kernel with ``_join_step_cells``. Payload
-    (val, clk) follows the surviving counter by a winner-select
-    reduction in the jnp epilogue, then the parked keyset-removes replay
-    once on the A-wide decoded table BEFORE the sibling-capacity check
-    (the tree join's transient-overflow semantics). Returns
-    ``(state, overflow[2])`` like ``ops.map.fold``."""
+    Two passes over the batch, each chunk by chunk with a running
+    accumulator, so the fold's temporaries are bounded by one chunk and
+    not by R. Counters: each chunk's slot tables decode to the dense
+    per-(key, actor) witness counters (``_decode_wide``), whose replica
+    fold is the cell-granular dot rule (the Pallas kernel with
+    ``_join_step_cells``); the chunk's result joins the accumulator by
+    the same rule. Then the parked keyset-removes replay once on the
+    folded counters BEFORE the sibling-capacity check (the tree join's
+    transient-overflow semantics), and the surviving cells, put in
+    canonical slot order, are the dots the result keeps. Payload: each
+    chunk supplies the val and clk of the kept dots it holds
+    (``_payload_of``); payload follows the dot, so the max over chunks
+    is exact. Returns ``(state, overflow[2])`` like ``ops.map.fold``."""
     if interpret is None:
         interpret = not _fused_backend()
     r, k, s = states.child.wact.shape
     a = states.top.shape[-1]
     tile_e = min(tile_e, max(k, 1))
     r_chunk = _pick_r_chunk(r, a, tile_e, r_chunk)
-    return _fold_fused_map_jit(states, tile_e, r_chunk, interpret)
+    return _fold_fused_map_jit(
+        states, tile_e, r_chunk, interpret, _map_chunk_rows(r, a, k))
+
+
+def _chunked(states, rows: int, step, acc):
+    """``step(acc, block)`` over the replica axis of ``states`` in blocks
+    of ``rows`` (a loop, so one block is live at a time), then once over
+    the remainder."""
+    r = jax.tree.leaves(states)[0].shape[0]
+    full, tail = divmod(r, rows)
+    cut = lambda lo, n: jax.tree.map(  # noqa: E731
+        lambda x: lax.dynamic_slice_in_dim(x, lo, n, axis=0), states)
+    if full == 1:
+        acc = step(acc, cut(0, rows))
+    elif full:
+        acc = lax.fori_loop(0, full, lambda i, c: step(c, cut(i * rows, rows)), acc)
+    if tail:
+        acc = step(acc, cut(full * rows, tail))
+    return acc
 
 
 @partial(jax.jit, static_argnames=("tile_e", "r_chunk", "interpret"))
-def _fold_fused_map_jit(states, tile_e, r_chunk, interpret):
+def _map_chunk_fold(top, wctr, tile_e, r_chunk, interpret):
+    """One chunk's counters ``[N, A, K]`` folded by the Pallas kernel's
+    cell rule: ``(top [A], wctr [A, K])``. A jit of its own so that the
+    kernel keeps this name in the compiled program and the device trace."""
+    return _fold_entries_fused(top, wctr, tile_e, r_chunk, interpret,
+                               cellwise=True, pre_t=True, out_t=True)
+
+
+@partial(jax.jit, static_argnames=("tile_e", "r_chunk", "interpret", "chunk_rows"))
+def _fold_fused_map_jit(states, tile_e, r_chunk, interpret, chunk_rows=None):
+    from ..utils.metrics import metrics
     from . import map as map_ops
+    from .mvreg import MVRegState
 
     r, k, s = states.child.wact.shape
     a = states.top.shape[-1]
-    wctr, val1, clk = _decode_wide(states.child, a)  # [R, A, K] K-minor
+    rows = chunk_rows or _map_chunk_rows(r, a, k)
+    # Static per compiled program: recorded once, when it is traced.
+    metrics.observe("pallas.map_fold.chunk_rows", rows)
+    metrics.observe("pallas.map_fold.chunks", -(-r // rows))
 
-    top, folded_w = _fold_entries_fused(
-        states.top, wctr, tile_e, r_chunk, interpret, cellwise=True,
-        pre_t=True, out_t=True,
-    )  # top [A], folded_w [A, K]
+    def fold_counters(acc, block):
+        top_b, child_b = block
+        top_c, w_c = _map_chunk_fold(
+            top_b, _decode_wide(child_b, a), tile_e, r_chunk, interpret)
+        top, w = _join_step_cells(acc[0][:, None], acc[1], top_c[:, None], w_c)
+        return top[:, 0], w
 
-    # Winner-select payload: the surviving counter's replica supplies
-    # val and clk (ties ⇒ same dot ⇒ same payload, max is safe).
-    match = (wctr == folded_w[None]) & (folded_w[None] > 0)
-    val1 = jnp.max(jnp.where(match, val1, 0), axis=0)              # [A, K]
-    clk = jnp.max(jnp.where(match[:, :, None, :], clk, 0), axis=0)  # [A, A, K]
+    zero = (jnp.zeros((a,), states.top.dtype), jnp.zeros((a, k), states.child.wctr.dtype))
+    top, folded_w = _chunked((states.top, states.child), rows, fold_counters, zero)
 
-    # Parked keyset-removes: union → dedupe → replay directly on the
+    # Parked keyset-removes, chunk by chunk: replay every one on the
     # K-minor cells (cell (y, k) dies iff some parked slot masks key k
-    # with a clock covering its dot) → drop caught-up → compact, then
-    # the sibling-capacity check — the tree join's transient-overflow
+    # with a clock covering its dot), and keep those still ahead of the
+    # folded top, united by clock in order of first appearance — what
+    # dedupe → drop caught-up → compact over all R·D slots keeps. Then
+    # the sibling-capacity check: the tree join's transient-overflow
     # semantics (replay precedes the capacity check).
     d = states.dcl.shape[-2]
-    dcl = states.dcl.reshape(r * d, a)
-    dkeys = states.dkeys.reshape(r * d, k)
-    dvalid = states.dvalid.reshape(r * d)
-    dcl, dkeys, dvalid = _dedupe_deferred(dcl, dkeys, dvalid)
 
-    def cover(maxcov, slot):
-        cl, keys, dv = slot
-        return _umax(maxcov, jnp.where(dv & keys[None, :], cl[:, None], 0)), None
+    def park(acc, block):
+        maxcov, pcl, pkeys, pvalid, of = acc
+        n = block[0].shape[0]
+        dcl = block[0].reshape(n * d, a)
+        dkeys = block[1].reshape(n * d, k)
+        dvalid = block[2].reshape(n * d)
+        live = dvalid[:, None] & dkeys                               # [N·D, K]
+        maxcov = _umax(maxcov, jnp.max(
+            jnp.where(live[:, None, :], dcl[:, :, None], 0), axis=0))  # [A, K]
+        ahead = dvalid & ~jnp.all(dcl <= top[None, :], axis=-1)
+        cl, keys, valid = _dedupe_deferred(
+            jnp.concatenate([pcl, dcl]), jnp.concatenate([pkeys, dkeys]),
+            jnp.concatenate([pvalid, ahead]))
+        cl, keys, valid, o = _compact_deferred(cl, keys, valid, d)
+        return maxcov, cl, keys, valid, of | o
 
-    maxcov, _ = lax.scan(cover, jnp.zeros_like(folded_w), (dcl, dkeys, dvalid))
-    kill = (folded_w > 0) & (folded_w <= maxcov)
-    folded_w = jnp.where(kill, 0, folded_w)
-    val1 = jnp.where(kill, 0, val1)
-    clk = jnp.where(kill[:, None, :], 0, clk)
-
-    still_ahead = ~jnp.all(dcl <= top[None, :], axis=-1)
-    dvalid = dvalid & still_ahead
-    dcl, dkeys, dvalid, d_of = _compact_deferred(dcl, dkeys, dvalid, d)
+    maxcov, dcl, dkeys, dvalid, d_of = _chunked(
+        (states.dcl, states.dkeys, states.dvalid), rows, park,
+        (jnp.zeros_like(folded_w), jnp.zeros((d, a), states.dcl.dtype),
+         jnp.zeros((d, k), bool), jnp.zeros((d,), bool), jnp.zeros((), bool)),
+    )
+    folded_w = jnp.where(folded_w <= maxcov, 0, folded_w)
 
     c_of = jnp.any(jnp.sum(folded_w > 0, axis=0) > s)
-    child = _wide_to_slots(folded_w, val1, clk, s)
+    wact, wctr, valid = _slot_order(folded_w, s)        # [S, K]
+    val1, clk = _chunked(
+        states.child, rows,
+        lambda acc, b: jax.tree.map(_umax, acc, _payload_of(b, wact, wctr)),
+        (jnp.zeros((s, k), jnp.uint32), jnp.zeros((s, a, k), states.child.clk.dtype)),
+    )
+    child = MVRegState(
+        wact=wact.T,
+        wctr=wctr.T,
+        clk=jnp.transpose(clk, (2, 0, 1)),
+        val=jnp.where(valid, val1.astype(jnp.int32) - 1, 0).T,
+        valid=valid.T,
+    )
     return (
         map_ops.MapState(
             top=top, child=child, dcl=dcl, dkeys=dkeys, dvalid=dvalid
         ),
-        jnp.stack([c_of, jnp.any(d_of)]),
+        jnp.stack([c_of, d_of]),
     )
 
 

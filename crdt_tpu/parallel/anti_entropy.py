@@ -731,6 +731,7 @@ def _mesh_fold_lattice(
     slots_fn=None,
     element_sharded: bool = False,
     donate: bool = False,
+    pad=None,
 ):
     """Shared scaffold for the map-family mesh folds: local log-tree
     fold per shard, replica-axis lattice-join all-reduce, and overflow
@@ -740,7 +741,12 @@ def _mesh_fold_lattice(
     (telemetry.py); the flag off traces exactly the flag-free program.
     ``donate=True`` consumes the input batch: the fold reduces the
     replica axis away so no output aliases it — the buffers are freed
-    as soon as the reduction lands (see ``mesh_fold``)."""
+    as soon as the reduction lands (see ``mesh_fold``). ``pad`` fits the
+    batch to the mesh inside the entry's ``ae.<kind>.prep`` span.
+
+    Spans, as ``mesh_fold``'s: ``ae.<kind>`` around the whole entry, and
+    under it ``.prep`` (padding, counters, depth gauge, program lookup),
+    ``.dispatch`` and ``.wait`` (until the converged state is ready)."""
 
     def build():
         @partial(
@@ -794,24 +800,32 @@ def _mesh_fold_lattice(
 
         return mesh_tel_fn
 
-    metrics.count(f"anti_entropy.{kind}_rounds")
-    metrics.count(
-        "anti_entropy.merges", max(jax.tree.leaves(state)[0].shape[0] - 1, 0)
-    )
-    metrics.observe("anti_entropy.state_bytes", state_nbytes(state))
-    observe_depth(f"anti_entropy.{kind}", state)
-    t0 = time.perf_counter()
     with tele.span(f"ae.{kind}"):
-        out = _cached(
-            kind, state, mesh, build_tel if telemetry else build, telemetry
-        )(state)
-        jax.block_until_ready(out)  # time device work, not async dispatch
-    _consume(donate, state)
-    if telemetry and tele.is_concrete(out[2]):
-        out = out[:2] + (tele.time_dispatch(
-            out[2], time.perf_counter() - t0
-        ),)
-        tele.record(kind, out[2])
+        with tele.span(f"ae.{kind}.prep"):
+            orig = state
+            if pad is not None:
+                state = pad(state)
+            metrics.count(f"anti_entropy.{kind}_rounds")
+            metrics.count(
+                "anti_entropy.merges",
+                max(jax.tree.leaves(state)[0].shape[0] - 1, 0),
+            )
+            metrics.observe("anti_entropy.state_bytes", state_nbytes(state))
+            observe_depth(f"anti_entropy.{kind}", state)
+            fn = _cached(
+                kind, state, mesh, build_tel if telemetry else build, telemetry
+            )
+        t0 = time.perf_counter()
+        with tele.span(f"ae.{kind}.dispatch"):
+            out = fn(state)
+        with tele.span(f"ae.{kind}.wait"):
+            jax.block_until_ready(out)  # time device work, not async dispatch
+        _consume(donate, state, orig)
+        if telemetry and tele.is_concrete(out[2]):
+            out = out[:2] + (tele.time_dispatch(
+                out[2], time.perf_counter() - t0
+            ),)
+            tele.record(kind, out[2])
     return out
 
 
@@ -827,14 +841,16 @@ def mesh_fold_map(
 
     Returns (converged state [no replica axis, key-sharded], overflow).
     """
-    state = pad_replicas_map(state, mesh.shape[REPLICA_AXIS])
-    state = pad_keys(state, mesh.shape[ELEMENT_AXIS])
+    def pad(st):
+        st = pad_replicas_map(st, mesh.shape[REPLICA_AXIS])
+        return pad_keys(st, mesh.shape[ELEMENT_AXIS])
+
     return _mesh_fold_lattice(
         "map_fold", state, mesh,
         map_ops.join, map_ops.fold,
         map_specs(), map_out_specs(),
         telemetry=telemetry, slots_fn=map_ops.changed_keys,
-        element_sharded=True, donate=donate,
+        element_sharded=True, donate=donate, pad=pad,
     )
 
 
@@ -848,9 +864,6 @@ def mesh_fold_map_orswot(
     lattice-join all-reduce plus the tiny slot-liveness reduction the
     dead-key scrub needs across key shards (ops/map_orswot.py
     ``_any_slots``). Returns (converged state, overflow[2])."""
-    state = pad_map_orswot(
-        state, mesh.shape[REPLICA_AXIS], mesh.shape[ELEMENT_AXIS]
-    )
     return _mesh_fold_lattice(
         "map_orswot_fold", state, mesh,
         partial(mo_ops.join, element_axis=ELEMENT_AXIS),
@@ -859,6 +872,8 @@ def mesh_fold_map_orswot(
         telemetry=telemetry,
         slots_fn=lambda a, b: ops.changed_members(a.core, b.core),
         element_sharded=True, donate=donate,
+        pad=lambda st: pad_map_orswot(
+            st, mesh.shape[REPLICA_AXIS], mesh.shape[ELEMENT_AXIS]),
     )
 
 
@@ -869,9 +884,6 @@ def mesh_fold_nested_map(
     """Full-mesh anti-entropy for ``Map<K1, Map<K2, MVReg>>`` over the
     (replica × outer-key) mesh (K1*K2 blocks per shard). Returns
     (converged state, overflow[3])."""
-    state = pad_nested_map(
-        state, mesh.shape[REPLICA_AXIS], mesh.shape[ELEMENT_AXIS]
-    )
     return _mesh_fold_lattice(
         "nested_map_fold", state, mesh,
         partial(nested_ops.join, element_axis=ELEMENT_AXIS),
@@ -880,6 +892,8 @@ def mesh_fold_nested_map(
         telemetry=telemetry,
         slots_fn=lambda a, b: map_ops.changed_keys(a.m, b.m),
         element_sharded=True, donate=donate,
+        pad=lambda st: pad_nested_map(
+            st, mesh.shape[REPLICA_AXIS], mesh.shape[ELEMENT_AXIS]),
     )
 
 
@@ -1421,7 +1435,6 @@ def mesh_fold_map3(state, mesh: Mesh, telemetry: bool = False,
     from ..ops import map3 as map3_ops
     from .mesh import map3_out_specs, map3_specs, pad_map3
 
-    state = pad_map3(state, mesh.shape[REPLICA_AXIS], mesh.shape[ELEMENT_AXIS])
     return _mesh_fold_lattice(
         "map3_fold", state, mesh,
         partial(map3_ops.join, element_axis=ELEMENT_AXIS),
@@ -1430,6 +1443,7 @@ def mesh_fold_map3(state, mesh: Mesh, telemetry: bool = False,
         telemetry=telemetry,
         slots_fn=lambda a, b: ops.changed_members(a.mo.core, b.mo.core),
         element_sharded=True, donate=donate,
+        pad=lambda st: pad_map3(st, mesh.shape[REPLICA_AXIS], mesh.shape[ELEMENT_AXIS]),
     )
 
 

@@ -6,6 +6,7 @@ import random
 import pytest
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from hypothesis import given, settings
 
@@ -13,6 +14,7 @@ from crdt_tpu.models.orswot import BatchedOrswot
 from crdt_tpu.ops import orswot as ops
 from crdt_tpu.ops.pallas_kernels import fold_fused
 from crdt_tpu.pure.orswot import Orswot
+from crdt_tpu.utils.metrics import metrics
 
 from strategies import seeds
 from test_fault_injection import _mint_streams
@@ -199,3 +201,214 @@ def test_fused_nested_map_fold_matches_tree_fold(seed):
     fused, off = fold_fused_level(mm.LEVEL, model.state, tile_e=2)
     assert bool(oft.any()) == bool(off.any())
     _tree_eq(tree, fused)
+
+
+# ---- the chunked Map<K, MVReg> fold against the tree fold and the plain
+# reference (benchmark/reference/map_mvreg.py) ------------------------------
+
+def _bench_module(sub: str, name: str):
+    """``benchmark/<sub>/<name>.py``, loaded by path (the benchmark's
+    directories are not packages)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmark", sub, f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"bench_{sub}_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+MAP_K, MAP_A, MAP_S, MAP_D = 64, 4, 2, 4
+
+
+def _sim_rows(ref, rng, r):
+    """``r`` replica states of a random causal history: one site per
+    actor puts (its next dot, its clock), removes keys under its clock,
+    receives other sites' removes late (they park while ahead) and merges
+    other sites. Rows are snapshots. Two actors write each key and two
+    removes (minted halfway) travel late, so no union of rows holds more siblings than the
+    slots or more parked clocks than the cap: the tree fold's per-join
+    capacity checks then cut nothing (the overflow cases are their own)."""
+    sites = [ref.Row(MAP_K, MAP_A) for _ in range(MAP_A)]
+    minted, snaps = [], []
+    for step in range(160):
+        i = int(rng.integers(MAP_A))
+        site = sites[i]
+        roll = rng.random()
+        if roll < 0.45:
+            c = int(site.top[i]) + 1
+            clock = site.top.copy()
+            clock[i] = c
+            key = 2 * int(rng.integers(MAP_K // 4)) + i // 2
+            site.put(i, c, key, clock, int(rng.integers(1 << 20)))
+        elif roll < 0.6:
+            keys = rng.choice(MAP_K // 2, size=int(rng.integers(1, 4)), replace=False)
+            site.rm(site.top.copy(), keys)
+            if step >= 80 and len(minted) < 2:
+                minted.append((site.top.copy(), keys))
+        elif roll < 0.75 and minted:
+            site.rm(*minted[int(rng.integers(len(minted)))])
+        else:
+            site.merge(sites[int(rng.integers(MAP_A))].clone())
+        snaps.append(site.clone())
+    rows = [snaps[int(j)].clone() for j in rng.integers(len(snaps), size=r)]
+    parked = [s for s in snaps if s.parked]
+    if parked:   # the first row holds parked removes
+        rows[0] = parked[int(rng.integers(len(parked)))].clone()
+    return rows
+
+
+def _siblings_over_rows(ref, rng, r):
+    """Three actors put concurrently on key 0; rows hold one or two of
+    the siblings (at the cap), so their union overflows it."""
+    sites = [ref.Row(MAP_K, MAP_A) for _ in range(3)]
+    for i, site in enumerate(sites):
+        for key in (0, 1 + i):
+            c = int(site.top[i]) + 1
+            clock = site.top.copy()
+            clock[i] = c
+            site.put(i, c, key, clock, 10 * i + key)
+    pair = sites[0].clone()
+    pair.merge(sites[1])
+    rows = [sites[0], sites[1], sites[2], pair]
+    return [rows[j % 4].clone() if j % 5 != 4 else ref.Row(MAP_K, MAP_A) for j in range(r)]
+
+
+def _parked_over_rows(ref, rng, r):
+    """Five removes park under distinct clocks ahead of every top, three
+    in the first row and two in the last, on keys nobody writes."""
+    rows = [ref.Row(MAP_K, MAP_A) for _ in range(r)]
+    for j, row in enumerate(rows[1:-1]):
+        c = int(row.top[j % MAP_A]) + 1
+        clock = row.top.copy()
+        clock[j % MAP_A] = c
+        row.put(j % MAP_A, c, j % 8, clock, j)
+    for n, row in ((0, rows[0]), (3, rows[0]), (1, rows[0]), (4, rows[-1]), (2, rows[-1])):
+        clock = np.zeros(MAP_A, np.uint32)
+        clock[3] = 50 + n
+        row.rm(clock, [MAP_K - 1 - n])
+    return rows
+
+
+def _map_state(ref, rows):
+    """Host reference rows as one batched ``MapState``."""
+    from crdt_tpu.ops.mvreg import MVRegState
+
+    tops, cols, dcl, dkeys, dvalid = [], [], [], [], []
+    for row in rows:
+        top, c, parked, _ = row.canonical(MAP_S)
+        tops.append(top)
+        cols.append(c)
+        cl = np.zeros((MAP_D, MAP_A), np.uint32)
+        ks = np.zeros((MAP_D, MAP_K), bool)
+        for s, (clock, keys) in enumerate(sorted(parked, key=lambda p: p[0])):
+            cl[s] = clock
+            ks[s, sorted(keys)] = True
+        dcl.append(cl)
+        dkeys.append(ks)
+        dvalid.append(np.arange(MAP_D) < len(parked))
+    stack = lambda xs: jnp.asarray(np.stack(xs))  # noqa: E731
+    child = MVRegState(*[stack([c[i] for c in cols]) for i in range(5)])
+    return map_ops.MapState(top=stack(tops), child=child, dcl=stack(dcl),
+                            dkeys=stack(dkeys), dvalid=stack(dvalid))
+
+
+@pytest.mark.parametrize("r,rows,flavor,seed", [
+    (1, 1, "history", 0), (5, 4, "history", 1), (13, 4, "history", 2),
+    (64, 16, "history", 3), (5, 2, "siblings_over", 0), (13, 8, "parked_over", 0),
+])
+def test_chunked_map_fold_matches_tree_fold_and_reference(monkeypatch, r, rows, flavor, seed):
+    """The map fold in replica chunks of ``rows`` (the HBM budget set so
+    that a chunk is that many replicas) is bit-identical to the slot-table
+    tree fold, both overflow flags included, and agrees with the plain
+    reference's merge of every replica."""
+    from crdt_tpu.ops import pallas_kernels as pk
+
+    ref = _bench_module("reference", "map_mvreg")
+    rng = np.random.default_rng(seed)
+    make = {"history": _sim_rows, "siblings_over": _siblings_over_rows,
+            "parked_over": _parked_over_rows}[flavor]
+    host = make(ref, rng, r)
+    state = _map_state(ref, host)
+    row_bytes = 8 * 128 * 4   # one replica's wide counters at A = 4, K = 64
+    monkeypatch.setattr(pk, "_MAP_CHUNK_BUDGET", rows * row_bytes)
+    assert pk._map_chunk_rows(r, MAP_A, MAP_K) == min(rows, r)
+
+    fused, of_fused = fold_fused_map(state, tile_e=32, interpret=True)
+    gauges = metrics.snapshot()["gauges"]
+    assert gauges["pallas.map_fold.chunk_rows"]["last"] == min(rows, r)
+    assert gauges["pallas.map_fold.chunks"]["last"] == -(-r // rows)
+    tree, of_tree = map_ops._tree_fold(state)
+    np.testing.assert_array_equal(np.asarray(of_fused), np.asarray(of_tree))
+    _tree_eq(tree, fused)
+
+    want = host[0].clone()
+    for row in host[1:]:
+        want.merge(row)
+    top, cols, parked, sib_over = want.canonical(MAP_S)
+    assert [bool(x) for x in np.asarray(of_fused)] == [sib_over, len(parked) > MAP_D]
+    np.testing.assert_array_equal(np.asarray(fused.top), top)
+    for got, exp in zip(fused.child, cols):
+        np.testing.assert_array_equal(np.asarray(got), exp)
+    got_parked = {(tuple(int(x) for x in cl), frozenset(np.nonzero(np.asarray(ks))[0].tolist()))
+                  for cl, ks, v in zip(np.asarray(fused.dcl), np.asarray(fused.dkeys),
+                                       np.asarray(fused.dvalid)) if v}
+    if len(parked) <= MAP_D:
+        assert got_parked == parked
+    else:
+        assert len(got_parked) == MAP_D and got_parked <= parked
+    at_cap = np.asarray(fused.child.valid).all(axis=-1)
+    assert {"history": r == 1 or (at_cap.any() and not at_cap.all()
+                                  and any(row.parked for row in host)),
+            "siblings_over": sib_over and at_cap[0],
+            "parked_over": len(parked) > MAP_D}[flavor]
+    if not any(row.parked for row in host):
+        # The device reference fold the chip check runs agrees too.
+        (rtop, rcols), over = ref.fold_rows(state.top, tuple(state.child))
+        np.testing.assert_array_equal(np.asarray(rtop), top)
+        assert (int(over) > 0) == sib_over
+        for got, exp in zip(rcols, cols):
+            np.testing.assert_array_equal(np.asarray(got), exp)
+
+
+def test_mesh_fold_map_fused_matches_reference_on_a_traffic_round(monkeypatch):
+    """``mesh_fold_map`` on a (1, 1) mesh, its local fold forced onto the
+    chunked fused path, against the plain reference on one round of the
+    ``map_mvreg_fold_1chip`` cell's traffic at a tiny size."""
+    from crdt_tpu.ops import pallas_kernels as pk
+    from crdt_tpu.ops.mvreg import MVRegState
+    from crdt_tpu.parallel import anti_entropy, make_mesh
+
+    gen_mod = _bench_module("gen", "map_replicas")
+    ref = _bench_module("reference", "map_mvreg")
+
+    fold = map_ops.fold
+    monkeypatch.setattr(map_ops, "fold", lambda st, prefer="auto": fold(st, prefer="fused"))
+    monkeypatch.setattr(anti_entropy, "_FN_CACHE", {})
+    monkeypatch.setattr(pk, "_MAP_CHUNK_BUDGET", 4 * 8 * 2048 * 4)   # 4 replicas a chunk
+    gen = gen_mod.Population((1 << 33) + 9, replicas=16, keys=2048, actors=8,
+                             sibling_cap=2, deferred_cap=4, counter_max=100,
+                             writer_puts=4, writer_removes=4)
+    rows = gen.block(gen.base(), 0, gen.r, 3)
+    cols = (rows.wact, rows.wctr, rows.clk, rows.val, rows.valid)
+    state = map_ops.MapState(top=rows.top, child=MVRegState(*cols), dcl=rows.dcl,
+                             dkeys=rows.dkeys, dvalid=rows.dvalid)
+    out, of = anti_entropy.mesh_fold_map(state, make_mesh(1, 1))
+    (top, want), over = ref.fold_rows(rows.top, cols)
+    assert not np.asarray(of).any() and int(over) == 0
+    np.testing.assert_array_equal(np.asarray(out.top), np.asarray(top))
+    for got, exp in zip(out.child, want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+    assert not np.asarray(out.dvalid).any() and not np.asarray(out.dkeys).any()
+    # The round's writes show: each pair's shared keys hold both siblings,
+    # a removed-only key is gone, a removed-while-put key holds the put.
+    puts, removes = (np.asarray(x) for x in gen.targets(3))
+    valid = np.asarray(out.child.valid)
+    wact = np.asarray(out.child.wact)
+    assert valid[puts[0, :gen.shared]].all()
+    np.testing.assert_array_equal(wact[puts[0, :gen.shared]], [[0, 1]] * gen.shared)
+    assert not valid[removes[0, gen.rm_put:]].any()
+    kept = removes[0, :gen.rm_put]
+    assert valid[kept, 0].all() and not valid[kept, 1].any() and (wact[kept, 0] == 2).all()

@@ -191,3 +191,33 @@ def test_entry_points_emit_their_spans_with_the_right_parents(tmp_path):
     assert st["fanout.dispatch"]["attrs"] == {"lanes": 4, "live": 3}
     # Every span is an annotation on the profiler's host plane.
     assert set(want) <= _host_annotations(str(tmp_path / "trace"))
+
+
+def test_map_fold_spans_record_under_a_profiler_and_nothing_otherwise(tmp_path):
+    """``mesh_fold_map``'s entry span and its prep / dispatch / wait
+    children, with the padding inside ``ae.map_fold.prep``; off a profiler
+    session the same calls record nothing."""
+    from crdt_tpu.ops import map as map_ops
+    from crdt_tpu.parallel import make_mesh
+    from crdt_tpu.parallel.anti_entropy import mesh_fold_map
+
+    mesh = make_mesh(2, 1)
+    state = map_ops.empty(8, 2, sibling_cap=2, deferred_cap=2, batch=(3,))  # padded to 4
+    before = metrics.snapshot()["gauges"].get("ae.map_fold_seconds", {}).get("n", 0)
+    jax.block_until_ready(mesh_fold_map(state, mesh))
+    assert tele.span_stats() == {} and tele.drain_events() == []
+    assert metrics.snapshot()["gauges"].get("ae.map_fold_seconds", {}).get("n", 0) == before
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        out, of = mesh_fold_map(state, mesh)
+    assert not np.asarray(of).any() and out.top.shape == (2,)
+    parents = {}
+    for e in tele.drain_events():
+        parents.setdefault(e["name"], set()).add(e["parent"])
+    want = {"ae.map_fold": {None}, "ae.map_fold.prep": {"ae.map_fold"},
+            "ae.map_fold.dispatch": {"ae.map_fold"}, "ae.map_fold.wait": {"ae.map_fold"}}
+    assert parents == want
+    st = tele.span_stats()
+    assert all(st[name]["n"] == 1 for name in want)
+    kids = sum(st[name]["total_s"] for name in want if name != "ae.map_fold")
+    assert st["ae.map_fold"]["self_s"] == pytest.approx(st["ae.map_fold"]["total_s"] - kids)
+    assert set(want) <= _host_annotations(str(tmp_path / "trace"))

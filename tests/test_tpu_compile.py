@@ -79,14 +79,23 @@ def test_fold_fused_config3_chunk(one_chip):
 
 
 def test_fold_fused_map_config4(one_chip):
+    """The ``map_mvreg_fold_1chip`` cell's fold: 64 replicas x 2^20 keys x
+    8 actors x 2 siblings. It decodes one replica chunk at a time, so its
+    temporaries do not grow with the batch: doubling R from 32 to 64 adds
+    less to them than one replica's state."""
     from crdt_tpu.ops import map as map_ops
     from crdt_tpu.ops.pallas_kernels import fold_fused_map
 
-    st = _shapes(
-        lambda: map_ops.empty(1_000_000, 8, sibling_cap=2, batch=(8,)),
-        one_chip,
-    )
-    _compile(lambda s: fold_fused_map(s, interpret=False), st)
+    temp = {}
+    for r in (32, 64):
+        st = _shapes(
+            lambda: map_ops.empty(1 << 20, 8, sibling_cap=2, deferred_cap=4, batch=(r,)),
+            one_chip,
+        )
+        mem = _compile(lambda s: fold_fused_map(s, interpret=False), st).memory_analysis()
+        temp[r] = mem.temp_size_in_bytes
+        one_replica = mem.argument_size_in_bytes // r
+    assert temp[64] - temp[32] < one_replica, (temp, one_replica)
 
 
 def test_nested_level_folds(one_chip):
