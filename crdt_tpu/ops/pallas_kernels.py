@@ -522,16 +522,23 @@ def _slot_order(folded_w, s: int):
     (unique per key, so no further tiebreak; ops/map._canon_child), as
     ``(wact [S, K], wctr [S, K], valid [S, K])``. Truncating when A > S
     keeps the lowest actors, as the tree join's canonical compaction
-    does; dead slots are zero."""
+    does; dead slots are zero.
+
+    Slot j takes the live actor with exactly j live actors below it (the
+    exclusive running count over the actor axis): elementwise work over
+    ``[A, K]`` per slot. A sort and its ``take_along_axis`` would lower to
+    per-element gathers over all A·K cells on the TPU."""
     a, k = folded_w.shape
     present = folded_w > 0
-    order = jnp.argsort(~present, axis=0, stable=True)  # [A, K] actor ids
-    valid = jnp.take_along_axis(present, order, axis=0)
-    wact = jnp.where(valid, order.astype(jnp.int32), 0)
-    wctr = jnp.where(valid, jnp.take_along_axis(folded_w, order, axis=0), 0)
-    if a >= s:
-        return wact[:s], wctr[:s], valid[:s]
-    pad = lambda x: jnp.pad(x, ((0, s - a), (0, 0)))  # noqa: E731
+    rank = jnp.cumsum(present, axis=0, dtype=jnp.int32) - present
+    ids = jnp.arange(a, dtype=jnp.int32)[:, None]
+    wact, wctr, valid = [], [], []
+    for j in range(min(s, a)):
+        sel = present & (rank == j)     # at most one actor per key
+        valid.append(jnp.any(sel, axis=0))
+        wact.append(jnp.max(jnp.where(sel, ids, 0), axis=0))
+        wctr.append(jnp.max(jnp.where(sel, folded_w, 0), axis=0))
+    pad = lambda x: jnp.pad(jnp.stack(x), ((0, max(s - a, 0)), (0, 0)))  # noqa: E731
     return pad(wact), pad(wctr), pad(valid)
 
 

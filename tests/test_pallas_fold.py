@@ -412,3 +412,53 @@ def test_mesh_fold_map_fused_matches_reference_on_a_traffic_round(monkeypatch):
     assert not valid[removes[0, gen.rm_put:]].any()
     kept = removes[0, :gen.rm_put]
     assert valid[kept, 0].all() and not valid[kept, 1].any() and (wact[kept, 0] == 2).all()
+
+
+def _slot_order_by_sort(folded_w, s):
+    """The canonical slot order by a stable argsort of the dead flags and
+    ``take_along_axis`` gathers: the reference for ``_slot_order``."""
+    a, k = folded_w.shape
+    present = folded_w > 0
+    order = jnp.argsort(~present, axis=0, stable=True)
+    valid = jnp.take_along_axis(present, order, axis=0)
+    wact = jnp.where(valid, order.astype(jnp.int32), 0)
+    wctr = jnp.where(valid, jnp.take_along_axis(folded_w, order, axis=0), 0)
+    if a >= s:
+        return wact[:s], wctr[:s], valid[:s]
+    pad = lambda x: jnp.pad(x, ((0, s - a), (0, 0)))  # noqa: E731
+    return pad(wact), pad(wctr), pad(valid)
+
+
+@pytest.mark.parametrize("density", [0.0, 0.2, 0.7, 1.0])
+@pytest.mark.parametrize("a,s", [(8, 2), (8, 8), (3, 12), (1, 2), (8, 1), (5, 3)])
+def test_slot_order_matches_sort_form(a, s, density):
+    """The running-count slot order equals the argsort + gather form bit
+    for bit (shapes, dtypes, zeros in dead slots), through the pad (A < S)
+    and truncate (A > S) branches and from no live cell to all live."""
+    from crdt_tpu.ops.pallas_kernels import _slot_order
+
+    rng = np.random.default_rng(a * 100 + s * 10 + int(density * 10))
+    k = 257
+    live = rng.random((a, k)) < density
+    folded_w = jnp.asarray(np.where(live, rng.integers(1, 1 << 31, (a, k)), 0)
+                           .astype(np.uint32))
+    got = _slot_order(folded_w, s)
+    want = _slot_order_by_sort(folded_w, s)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape == (s, k) and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert int(np.asarray(got[2]).sum()) == int(np.minimum(live.sum(0), s).sum())
+
+
+def test_slot_order_lowers_without_sort_or_gather():
+    """At the map cell's width ([8, 2^20] counters, S = 2) the slot order
+    lowers to elementwise work: no sort and no gather, which the TPU runs
+    one element at a time. The sort form, lowered the same way, has both."""
+    from crdt_tpu.ops.pallas_kernels import _slot_order
+
+    spec = jax.ShapeDtypeStruct((8, 1 << 20), jnp.uint32)
+    text = lambda f: jax.jit(lambda w: f(w, 2)).lower(spec).as_text().lower()  # noqa: E731
+    new = text(_slot_order)
+    assert "sort" not in new and "gather" not in new
+    old = text(_slot_order_by_sort)
+    assert "sort" in old and "gather" in old
