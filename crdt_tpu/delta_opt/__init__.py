@@ -20,7 +20,8 @@ Three cooperating pieces (see each module's docstring):
 - :mod:`.heal` — the **post-heal state-driven sync mode**: a healed
   partition resyncs by shipping each rank's decomposition over the
   pre-divergence snapshot instead of full states, bit-identical to
-  full-state gossip (``bench.py --heal`` measures the win).
+  full-state gossip (tests/test_delta_opt.py pins it under 25% of the
+  full-state bytes).
 
 Plus :func:`static_checks` — the ``decomp`` section of
 tools/run_static_checks.py: decomposition registry coverage and the

@@ -83,8 +83,8 @@ def resync(kind: str, states, since):
     residuals), reconstruct, and fold with the kind's registered join.
     Returns ``(healed [P, ...], ResyncReport)`` — ``healed`` is the
     full-join fixpoint broadcast to every rank, bit-identical to
-    full-state gossip over the same rows (tests/test_delta_opt.py and
-    the ``bench.py --heal`` leg both pin it).
+    full-state gossip over the same rows (tests/test_delta_opt.py pins
+    it).
 
     Counters: ``delta_opt.resync_runs``,
     ``delta_opt.resync_bytes_shipped`` / ``_full`` (plus per-kind

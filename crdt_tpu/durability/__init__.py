@@ -23,8 +23,8 @@ this package makes the *host process* survive. Four cooperating pieces
   (the ``delta_opt/heal.py`` pattern), plus the **log-suffix rejoin**
   that upgrades PR 8's membership contract: a restarted rank recovers
   locally and ships snapshot-generation + log-suffix divergence lanes
-  instead of receiving full state (``bench.py --recovery`` measures
-  the byte win).
+  instead of receiving full state (tests/test_durability.py pins the
+  byte win).
 - :mod:`.crashpoints` — **deterministic crash-point injection**: every
   durability I/O boundary registers a named crashpoint; the fuzz loop
   kills at each one, recovers, and asserts bit-identity with the

@@ -27,9 +27,9 @@ ships only its join-irreducible decomposition over the recovered state
 instead of a full state; reconstruction is bit-exact regardless of
 whether the recovered state is a true lower bound (the positional
 diff is unconditional — heal.py's argument), and the final join keeps
-any recovered-but-unreplicated local content. ``bench.py --recovery``
-measures the byte win (< 25% of full-state resync is the acceptance
-gate).
+any recovered-but-unreplicated local content. The byte win (< 25% of
+full-state resync) is pinned by
+``tests/test_durability.py::test_rejoin_ships_fraction_and_lands_bit_identical``.
 """
 
 from __future__ import annotations

@@ -19,8 +19,7 @@ Three sinks:
   collectors;
 - :func:`drain_jsonl` — append-only JSONL, one self-describing record
   per line (``{"record": "snapshot"|"telemetry"|"span", "ts": ...}``),
-  the trajectory format ``bench.py --metrics-out`` writes and
-  ``tools/check_telemetry_schema.py`` validates (committed schema:
+  the trajectory format ``tools/check_telemetry_schema.py`` validates (committed schema:
   ``tools/telemetry_schema.json`` — drift fails tier-1);
 - :func:`health` — one at-a-glance JSON snapshot (live_ranks,
   generation, frontier_lag, residue, last durable WAL watermark, the

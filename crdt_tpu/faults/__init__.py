@@ -38,7 +38,7 @@ fault-free fixpoint):
   irredundant join decomposition over a pre-divergence snapshot
   ``since`` (any mutually-known lower bound, e.g. the last certified
   fixpoint), so a partition that diverged by a handful of rows heals
-  for a fraction of full-state bytes (``bench.py --heal`` measures
+  for a fraction of full-state bytes (tests/test_delta_opt.py pins
   the ratio; the reconstruction law pins exactness per kind).
 """
 

@@ -11,7 +11,7 @@ that (a) rebuilds the ring permutation over live ranks only
 lint holds) and (b) removes the evicted rank's top from the frontier
 ``pmin``, unpinning reclamation.
 
-Protocol (the chaos tests and ``bench.py --chaos`` walk it end to end):
+Protocol (the chaos tests walk it end to end):
 
 1. run mesh rounds with ``faults=tracker.plan(base)``;
 2. feed the returned counters to :meth:`Membership.observe` — a rank
@@ -35,8 +35,8 @@ Protocol (the chaos tests and ``bench.py --chaos`` walk it end to end):
      ships only its join-irreducible decomposition over the recovered
      state (reconstruction is positionally bit-exact whatever the
      bound, and the final join keeps recovered-but-unreplicated local
-     content) — < 25% of full-state bytes on the ``bench.py
-     --recovery`` gate.
+     content) — < 25% of full-state bytes
+     (``tests/test_durability.py``).
    - **bootstrap-from-⊥** (ISSUE 11, ``crdt_tpu.scaleout.bootstrap``):
      the rank re-enters as a NEW member through the scale-out admit
      path — its causal lower bound is ⊥ (or a PR 10 snapshot as the

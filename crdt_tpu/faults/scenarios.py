@@ -1,8 +1,8 @@
 """Shared fault-scenario generators (SURVEY §6.3 delivery contract).
 
 These were minted inside tests/test_fault_injection.py; the chaos-soak
-suite and ``bench.py --chaos`` need the SAME schedule semantics, so the
-generators live here once instead of drifting as copies. The delivery
+suite needs the SAME schedule semantics, so the generators live here
+once instead of drifting as copies. The delivery
 contract they encode:
 
 - per-origin causal order is preserved (each site's own op stream is

@@ -104,7 +104,7 @@ def read_local(
 def _micro_federation(*, n_tenants: int = 8):
     """A two-region process-simulated federation on a 1×1 mesh —
     the detector/static-check workbench (no durable tier, no WAL:
-    those live in the failover tests and the bench leg)."""
+    those live in the failover tests)."""
     from ..parallel import make_mesh
     from ..serve.ingest import IngestQueue
     from ..serve.superblock import Superblock

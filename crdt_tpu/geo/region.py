@@ -25,8 +25,7 @@ with the region hosting them. This module federates N such regions:
   recent local writes (``local_writes``, stamped at the federation
   front door). Global tenant population × regions must NOT multiply
   device memory — the resident lane count per region is bounded by
-  home ∪ local-interest, which ``bench.py --geo`` measures rather
-  than asserts.
+  home ∪ local-interest.
 - :class:`Federation` — the front door: writes route to the tenant's
   HOME region's ingest queue (the ack point stays the home region's
   :class:`~crdt_tpu.serve.wal.ServeWal` group commit — ``flush`` on

@@ -102,7 +102,7 @@ def sampled(tenant: int, sample: int) -> bool:
 
 def sampled_mask(n_tenants: int, sample: int) -> np.ndarray:
     """Vectorized :func:`sampled` over the dense id range
-    ``[0, n_tenants)`` — the bench legs use this to pre-register a
+    ``[0, n_tenants)`` — callers use this to pre-register a
     fan-out subscriber per traced tenant so every sampled journey can
     complete (freshness is submit→client-ack)."""
     n = int(n_tenants)
@@ -435,8 +435,8 @@ _tracer: Optional[Tracer] = None
 
 def install_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:
     """Install (or with ``None`` remove) the process-global tracer
-    every hook site feeds. Returns the PREVIOUS tracer so tests and
-    bench legs can restore it."""
+    every hook site feeds. Returns the PREVIOUS tracer so callers can
+    restore it."""
     global _tracer
     with _install_lock:
         prev, _tracer = _tracer, tracer

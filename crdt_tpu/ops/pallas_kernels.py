@@ -150,20 +150,12 @@ def _fold_entries_fused(
     tile_e: int,
     r_chunk: int,
     interpret: bool,
-    n_passes: int = 1,
     cellwise: bool = False,
     pre_t: bool = False,
     out_t: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused fold of the entry matrices only: ``top[R, A]``,
     ``ctr[R, E, A]`` → ``(top[A], ctr[E, A])``.
-
-    ``n_passes > 1`` makes the grid re-walk the resident replica chunk
-    that many times, accumulating into the same output block. Because
-    the join is idempotent the result is unchanged, but the DMA and
-    compute stream is exactly that of folding ``n_passes * R`` distinct
-    replicas — the honest way to time a config-3-scale stream whose full
-    dot-state exceeds HBM (bench.py), with one dispatch.
 
     ``cellwise`` selects the cell-granular MVReg dot rule
     (``_join_step_cells``) instead of the orswot element rule.
@@ -201,7 +193,9 @@ def _fold_entries_fused(
         ),
         # Replica chunks on the inner (fastest) axis so the output block
         # accumulates across them before the E-tile advances.
-        grid=(e_padded // tile_e, n_passes * r_steps),
+        # ``j % r_steps`` is ``j`` on this grid; the modulo keeps the
+        # index map, and so the compiled kernel, as the cells measure it.
+        grid=(e_padded // tile_e, r_steps),
         in_specs=[
             pl.BlockSpec(
                 (rc, a, 1), lambda i, j: (j % r_steps, 0, 0), memory_space=pltpu.VMEM
@@ -323,8 +317,7 @@ def _fused_backend() -> bool:
     """THE backend-dispatch decision, in one place: the fused Pallas
     kernels run where they compile to Mosaic (the TPU); everywhere else
     "fused" would mean the Pallas *interpreter*, orders of magnitude
-    slower than XLA:CPU. bench.py labels its reported path with this
-    same predicate so cross-round numbers stay comparable."""
+    slower than XLA:CPU."""
     return jax.default_backend() == "tpu"
 
 
@@ -333,7 +326,6 @@ def fold_fused(
     tile_e: int = 512,
     r_chunk: Optional[int] = None,
     interpret: Optional[bool] = None,
-    n_passes: int = 1,
 ) -> Tuple[OrswotState, jax.Array]:
     """Drop-in replacement for ``ops.orswot.fold`` (same result, same
     overflow flag) with the replica reduction fused into one HBM pass.
@@ -341,16 +333,13 @@ def fold_fused(
     ``r_chunk`` defaults to a VMEM-safe size for the given actor count;
     ``interpret`` defaults to auto: compiled on TPU, interpreter
     elsewhere (CPU tests exercise the same kernel semantics).
-    ``n_passes`` re-walks the replica batch that many times (identical
-    result by idempotence; used by bench.py to time a stream of
-    ``n_passes * R`` replicas in one dispatch).
     """
     if interpret is None:
         interpret = not _fused_backend()
     r, e, a = states.ctr.shape
     tile_e = min(tile_e, max(e, 1))
     r_chunk = _pick_r_chunk(r, a, tile_e, r_chunk)
-    return _fold_fused_jit(states, tile_e, r_chunk, interpret, n_passes)
+    return _fold_fused_jit(states, tile_e, r_chunk, interpret)
 
 
 def fold_auto(states: OrswotState, prefer: str = "auto"):
@@ -373,13 +362,12 @@ def fold_auto(states: OrswotState, prefer: str = "auto"):
     return tree_fold(states)
 
 
-@partial(jax.jit, static_argnames=("tile_e", "r_chunk", "interpret", "n_passes"))
+@partial(jax.jit, static_argnames=("tile_e", "r_chunk", "interpret"))
 def _fold_fused_jit(
     states: OrswotState,
     tile_e: int,
     r_chunk: int,
     interpret: bool,
-    n_passes: int = 1,
 ) -> Tuple[OrswotState, jax.Array]:
     r, e, a = states.ctr.shape
     top, ctr = _fold_entries_fused(
@@ -388,7 +376,6 @@ def _fold_fused_jit(
         tile_e=tile_e,
         r_chunk=r_chunk,
         interpret=interpret,
-        n_passes=n_passes,
     )
 
     # Deferred epilogue (stock jnp; see module docstring): union every

@@ -254,8 +254,8 @@ def compact_tenants(tk: TenantKind, superblock, frontier):
 def sequential_oracle(tk: TenantKind, state, ops_list):
     """The per-tenant SEQUENTIAL oracle: apply one tenant's op stream
     one dispatch at a time on its unbatched state — the bit-identity
-    reference for the coalesced slab apply (``bench.py --serve`` and
-    tests/test_serve.py both gate on it). ``ops_list`` entries are
+    reference for the coalesced slab apply (tests/test_serve.py gates
+    on it). ``ops_list`` entries are
     ``(kind, actor, ctr, clock, member)`` host tuples."""
     for k, actor, ctr, clock, member in ops_list:
         if k == ADD:

@@ -799,9 +799,8 @@ def mesh_stream_fold_sparse(
     wal_every: int = 0, wal_base: int = 0,
 ):
     """Stream-fold SPARSE (segment-encoded) ORSWOT replica blocks
-    ``[B, ...]`` into one converged state — the flagship arbitrary-N
-    driver (``bench.py --flagship`` runs the 10,240 x 1M shape through
-    it). Returns ``(state, overflow[2[, Telemetry]])``; semantics and
+    ``[B, ...]`` into one converged state — the arbitrary-N driver.
+    Returns ``(state, overflow[2[, Telemetry]])``; semantics and
     flags (incl. the ``wal=`` durable-resume contract) per the module
     docstring."""
     return _stream_fold(

@@ -19,10 +19,9 @@ governor has enforced since ISSUE 5.
 Decisions are RECOMMENDATIONS (:class:`AutoscaleDecision`): the caller
 executes ``ScaleoutMesh.admit``/``drain`` — the drain still goes
 through its certificate, so a bad policy can waste a flush but can
-never strand content or void a convergence certificate. The bench leg
-(``bench.py --scaleout``) wires the loop end to end: spike → debounced
-admit → sustained merges/s rises; quiet → debounced drain → certified
-scale-in.
+never strand content or void a convergence certificate.
+``tests/test_scaleout.py`` drives the loop: load → debounced admit;
+quiet → debounced drain.
 """
 
 from __future__ import annotations
@@ -89,7 +88,7 @@ class Autoscaler:
         ``frontier_lag``, the streaming overlap-MISS fraction, the
         normalized DCN retry count, and an optional explicit ``load``
         (an ingest-side offered-load fraction the mesh cannot see from
-        its own kernels — the bench leg's traffic spike). Max, not
+        its own kernels — a traffic spike). Max, not
         mean: ANY saturated subsystem is a reason to add capacity, and
         a mesh is only quiet when every signal is."""
         worst = 0.0 if load is None else min(max(float(load), 0.0), 1.0)

@@ -16,8 +16,7 @@ This module is that path with the base generalized:
 - **warm start** (``base=`` a PR 10 snapshot state) — the newcomer (or
   a rejoining-as-new rank) restores the snapshot locally first, and
   the wire carries only ``decompose(live, snapshot)``: the log suffix.
-  The ``bench.py --scaleout`` gate pins this at < 25% of full-state
-  bytes.
+  ``tests/test_scaleout.py`` pins this at < 25% of full-state bytes.
 
 The wire is REAL in the degraded sense: under a ``faults=``
 :class:`~crdt_tpu.faults.inject.FaultPlan` every shipped segment

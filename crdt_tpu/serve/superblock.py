@@ -16,8 +16,7 @@ host-side ``tenant → lane`` indirection —
   (crdt_tpu/serve/evict.py), re-warming on next touch into whatever
   lane is free — which is why the device footprint is
   ``n_lanes × row_bytes`` while the SERVED population is
-  ``n_tenants`` (the peak-resident vs all-resident ratio
-  ``bench.py --serve`` reports);
+  ``n_tenants``;
 - an exhausted pool raises :class:`LanePressure`; the evictor turns
   that into evict-coldest-then-restore (serving-tier paging).
 

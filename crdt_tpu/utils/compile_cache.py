@@ -1,5 +1,5 @@
 """JAX's persistent compilation cache, placed in one way for every entry
-point (tests, ``bench.py``, ``chip_smoke.py``, the static checks).
+point (tests, ``benchmark/run.py``, the static checks).
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
 other path is set here. Otherwise the cache lives at ``<checkout>/.jax_cache``:

@@ -1,6 +1,8 @@
 """Config / flag system (SURVEY.md §6.6): backend selection is the
 feature-flag analog and drives the A/B gate."""
 
+import os
+
 import pytest
 
 from crdt_tpu.config import config, configure, configured, replicaset
@@ -298,3 +300,23 @@ def test_counter_dtype_u64_and_saturation_trap():
     pn = BatchedPNCounter(1, actors=Interner(["a"]))
     with pytest.raises(ValueError):
         pn.inc(0, "a", steps=1 << 33)
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    import jax
+
+    from crdt_tpu.utils import compile_cache
+
+    # Set from outside: used as is, and no path is set in code.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(jax.config, "update", lambda k, v: updates.append(k))
+    updates = []
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert "jax_compilation_cache_dir" not in updates
+    # Unset: the fixed path under the checkout, exported to children.
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == want
+    assert "jax_compilation_cache_dir" in updates

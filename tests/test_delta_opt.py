@@ -404,8 +404,7 @@ def test_resync_ships_only_the_divergence_set():
     """The headline bandwidth claim at a realistic shape: 8 ranks
     diverge by a handful of rows over a large synced base — the
     decomposition resync ships a small fraction of what full-state
-    resync would (< 25%, the ISSUE acceptance bar; bench.py --heal
-    measures the same end-to-end after a real FaultPlan partition)."""
+    resync would (< 25%)."""
     from crdt_tpu.delta_opt import resync
 
     # A wide synced base: 256 members all present everywhere, then each

@@ -148,3 +148,44 @@ def test_state_nbytes_matches_numpy():
     assert state_nbytes(state) == sum(
         np.asarray(x).nbytes for x in jax.tree.leaves(state)
     )
+
+
+def test_deferred_depth_counts_all_buffer_levels():
+    from crdt_tpu.ops import map3 as map3_ops
+
+    st = map3_ops.empty(2, 2, 2, 4, deferred_cap=3, batch=(5,))
+    assert deferred_depth(st) == 0.0
+    # Mark parked slots at two different nesting levels of one replica
+    # and one at another: max-per-replica must see the 2-slot replica.
+    st = st._replace(odvalid=st.odvalid.at[1, 0].set(True))
+    st = st._replace(
+        mo=st.mo._replace(kdvalid=st.mo.kdvalid.at[1, 1].set(True))
+    )
+    st = st._replace(
+        mo=st.mo._replace(
+            core=st.mo.core._replace(
+                dvalid=st.mo.core.dvalid.at[3, 2].set(True)
+            )
+        )
+    )
+    assert deferred_depth(st) == 2.0  # replica 1 holds two live slots
+
+    metrics.reset()
+    observe_depth("t", st)
+    snap = metrics.snapshot()
+    assert snap["gauges"]["t.deferred_depth"]["last"] == 2.0
+
+
+def test_anti_entropy_records_depth_and_merges():
+
+    from crdt_tpu.models import BatchedOrswot
+    from crdt_tpu.parallel.anti_entropy import mesh_fold
+    from crdt_tpu.parallel.mesh import make_mesh
+
+    metrics.reset()
+    mesh = make_mesh(4, 2)
+    m = BatchedOrswot(4, 16, 4, 2)
+    mesh_fold(m.state, mesh)
+    snap = metrics.snapshot()
+    assert snap["counters"]["anti_entropy.merges"] >= 3
+    assert "anti_entropy.orswot_fold.deferred_depth" in snap["gauges"]

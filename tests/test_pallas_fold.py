@@ -66,25 +66,6 @@ def test_fused_fold_matches_oracle(seed):
     assert out.to_pure(0) == oracle
 
 
-@given(seeds)
-@settings(max_examples=6, deadline=None)
-def test_multi_pass_stream_is_idempotent(seed):
-    # bench.py times n_passes re-walks of the chunk; by idempotence the
-    # result must equal the single-pass fold bit for bit.
-    rng = random.Random(seed)
-    n = rng.randint(2, 6)
-    sites, _ = _mint_streams(rng, n, 12)
-    model = BatchedOrswot.from_pure(sites)
-    one, of1 = fold_fused(model.state, tile_e=4, n_passes=1)
-    three, of3 = fold_fused(model.state, tile_e=4, n_passes=3)
-    assert bool(of1) == bool(of3)
-    for name in ("top", "ctr", "dvalid"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(one, name)), np.asarray(getattr(three, name)),
-            err_msg=name,
-        )
-
-
 def test_fused_fold_with_parked_removes():
     # A remove parked ahead of every top must replay against the folded
     # entries exactly as the tree fold does.

@@ -207,14 +207,3 @@ def test_span_jsonl_file_sink(tmp_path):
     assert cts.validate_jsonl(path) == []
     [rec] = [json.loads(l) for l in open(path)]
     assert rec["name"] == "file_span"
-
-
-def test_bench_metrics_out_flag(tmp_path):
-    sys.path.insert(0, ROOT)
-    import bench
-
-    args = bench.parse_args(["--metrics-out", str(tmp_path / "m.jsonl")])
-    assert args.metrics_out.endswith("m.jsonl")
-    assert bench.parse_args([]).metrics_out == os.environ.get(
-        "BENCH_METRICS_OUT", ""
-    )

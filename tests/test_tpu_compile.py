@@ -75,7 +75,7 @@ def test_fold_fused_config3_chunk(one_chip):
     from crdt_tpu.ops.pallas_kernels import fold_fused
 
     st = _shapes(lambda: ops.empty(102400, 8, 4, batch=(512,)), one_chip)
-    _compile(lambda s: fold_fused(s, interpret=False, n_passes=20), st)
+    _compile(lambda s: fold_fused(s, interpret=False), st)
 
 
 def test_fold_fused_map_config4(one_chip):

@@ -2,10 +2,9 @@
 """Validate observability exports against the committed schema.
 
 ``tools/telemetry_schema.json`` is the contract for everything the
-exporter emits: JSONL lines from ``crdt_tpu.exporter.drain_jsonl`` /
-``bench.py --metrics-out`` (snapshot / telemetry / span records) and
-bare registry snapshots (``metrics.snapshot()``, including the copy
-embedded in the bench headline's ``metrics`` field). This checker is
+exporter emits: JSONL lines from ``crdt_tpu.exporter.drain_jsonl``
+(snapshot / telemetry / span records) and bare registry snapshots
+(``metrics.snapshot()``). This checker is
 deliberately dependency-free (no jsonschema on the CI image) and runs
 as a fast tier-1 test (tests/test_telemetry_schema.py), so exporter
 drift — a renamed field, a stringly-typed counter, a NaN smuggled into

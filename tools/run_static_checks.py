@@ -196,8 +196,7 @@ SECTIONS = (
 )
 
 # Directories the fallback linter walks (ruff takes its own config).
-LINT_TARGETS = ("crdt_tpu", "tools", "tests", "examples", "bench.py",
-                "chip_smoke.py")
+LINT_TARGETS = ("crdt_tpu", "tools", "tests", "examples")
 
 
 # ---- section: lint -------------------------------------------------------

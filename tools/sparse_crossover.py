@@ -28,12 +28,12 @@ sys.path.insert(0, ROOT)
 
 
 def _marginal(join1, xa, xb, n: int | None = None, iters: int = 5) -> float:
-    """Chip-side marginal per-join time via the K-vs-2K method (bench.py
-    module docstring): a ``fori_loop`` chain of ``n`` joins runs in ONE
+    """Chip-side marginal per-join time via the K-vs-2K method: a
+    ``fori_loop`` chain of ``n`` joins runs in ONE
     dispatch, so the fixed per-dispatch overhead cancels in
     ``t(2n) − t(n)``. The trip count is a traced operand, so both
     lengths share one compile. The n- and 2n-timings interleave within
-    one loop (bench.py's convention) so slow host drift cancels too,
+    one loop so slow host drift cancels too,
     and a non-positive marginal falls back to the conservative
     ``t(2n)/2n`` bound instead of letting jitter fabricate a 0-ms
     winner."""

@@ -2,19 +2,20 @@
 
 The r3 sweep fixed the VMEM block budget at 1 MiB and the default
 tile_e at 512 (ops/pallas_kernels.py `_VMEM_BLOCK_BUDGET`). This tool
-re-measures the neighborhood on the real toolchain at the bench
-config-3 stream shape so the defaults are evidence, not folklore:
+re-measures the neighborhood on the real toolchain at a config-3-like
+stream shape so the defaults are evidence, not folklore:
 
     python tools/tile_sweep.py                # sweep, print a ranked table
     python tools/tile_sweep.py --write-table  # sweep AND commit the winner
                                               # into tools/tile_table.json
 
-For each candidate it times the same marginal K-vs-2K stream bench.py
-uses (independent of the fixed dispatch overhead) and reports achieved
-GB/s. Combos that fail Mosaic compilation are reported as such and
-skipped — that is data too (the 4 MiB block failure is recorded in the
-kernel's module docstring). Run it on the chip in one process (libtpu
-is process-exclusive).
+For each candidate it times K and then 2K back-to-back dispatches of
+the same resident chunk and takes the difference, the time of K folds
+independent of fixed start-up costs, and reports achieved GB/s. Combos
+that fail Mosaic compilation are reported as such and skipped — that
+is data too (the 4 MiB block failure is recorded in the kernel's
+module docstring). Run it on the chip in one process (libtpu is
+process-exclusive).
 
 ``--write-table`` closes the loop that made sweep results write-only:
 the best measured (tile_e, r_chunk) for this shape's actor count is
@@ -38,12 +39,34 @@ sys.path.insert(0, ROOT)
 # that a full sweep fits one chip call. Override via env.
 R = int(os.environ.get("SWEEP_REPLICAS", 2048))
 E = int(os.environ.get("SWEEP_ELEMS", 32768))
-PASSES = int(os.environ.get("SWEEP_PASSES", 4))
+PASSES = int(os.environ.get("SWEEP_PASSES", 4))  # K: folds per timed run
+
+
+def make_chunk_on_device(r, e, a=8, deferred_cap=4):
+    """``r`` replicas of ``e`` elements, made in device memory under jit
+    (a multi-GB push from the host would time the host link instead):
+    ~70% of (element, actor) dots present, each replica's top covering
+    its dots."""
+    import jax
+    import jax.numpy as jnp
+
+    from crdt_tpu.ops import orswot as ops
+
+    def make(key):
+        k1, k2, k3 = jax.random.split(key, 3)
+        ctr = jax.random.randint(k1, (r, e, a), 0, 100, dtype=jnp.uint32)
+        keep = jax.random.randint(k2, (r, e, a), 0, 10, dtype=jnp.uint32) >= 3
+        ctr = jnp.where(keep, ctr, 0)
+        extra = jax.random.randint(k3, (r, a), 0, 100, dtype=jnp.uint32)
+        top = jnp.maximum(ctr.max(axis=1), extra)
+        return ops.empty(e, a, deferred_cap=deferred_cap, batch=(r,))._replace(
+            top=top, ctr=ctr
+        )
+
+    return jax.jit(make)(jax.random.key(42))
 
 
 def main() -> int:
-    import bench
-
     import jax
 
     if jax.devices()[0].platform != "tpu":
@@ -56,24 +79,22 @@ def main() -> int:
 
     from crdt_tpu.ops.pallas_kernels import fold_fused
 
-    chunk = bench.make_chunk_on_device(R, E)
+    chunk = make_chunk_on_device(R, E)
     a = chunk.ctr.shape[-1]
     nbytes = chunk.ctr.nbytes + chunk.top.nbytes
 
     def measure(tile_e: int, r_chunk: int):
         # Warm/compile, correctness vs the default config, then the
-        # marginal-stream timing: (2K passes) - (K passes) over the
-        # resident chunk isolates pure stream time.
+        # marginal timing: (2K dispatches) - (K dispatches) of the
+        # resident chunk isolates the time of K folds.
         out, _ = fold_fused(chunk, tile_e=tile_e, r_chunk=r_chunk)
         jax.block_until_ready(out.ctr)
 
         def run(n):
-            o, _ = fold_fused(
-                chunk, tile_e=tile_e, r_chunk=r_chunk, n_passes=n
-            )
+            for _ in range(n):
+                o, _ = fold_fused(chunk, tile_e=tile_e, r_chunk=r_chunk)
             jax.block_until_ready(o.ctr)
 
-        run(PASSES), run(2 * PASSES)  # compile both pass counts
         ts = []
         for _ in range(3):
             t0 = time.perf_counter()
@@ -139,7 +160,7 @@ def main() -> int:
 
 def sweep_wire() -> int:
     """Sweep the fused WIRE kernel's row chunk (ops/wire_kernels.py)
-    at the bench quick-comms packet shape: one fused pack pass per
+    at a δ-ring packet shape: one fused pack pass per
     candidate, correctness pinned bit-identical against the default
     chunk, marginal timing over repeated packs. ``--write-table``
     commits the winner under ``family: "wire"`` — the fold family's
