@@ -31,16 +31,23 @@ Mosaic constraints shape two choices here:
   which left the VPU idle and capped the first version at ~136 GB/s —
   disappears, so the stream runs near DMA speed.
 
-Only the entry matrices fold in-kernel. The deferred-removal buffers are
-tiny ([R, D, A] clocks + [R, D, E] masks with D ≈ 4–8) and their replay
-is a pointwise mask over the folded result, so the wrapper handles them
-with stock jnp (XLA fuses it into the epilogue): union all parked
-removes, replay once against the folded entries, drop caught-up slots.
-Replaying once at the end is equivalent to the pairwise join's
-replay-at-every-node because replay is idempotent and monotone (it
-zeroes exactly the dots the rm clocks cover, which no join can
-resurrect past the final replay), and a slot is always replayed before
-the catch-up drop — the property suite pins fused == tree fold.
+Only the entry matrices fold in-kernel. The deferred-removal buffers
+([R, D, A] clocks + [R, D, E] masks with D ≈ 4–8) replay as a pointwise
+mask over the folded result, so the wrapper handles them with stock jnp
+after the kernel: union all parked removes, replay once against the
+folded entries, drop caught-up slots, compact to D. Replaying once at
+the end is equivalent to the pairwise join's replay-at-every-node
+because replay is idempotent and monotone (it zeroes exactly the dots
+the rm clocks cover, which no join can resurrect past the final
+replay), and a slot is always replayed before the catch-up drop — the
+property suite pins fused == tree fold.
+
+The union's dedupe is quadratic in its R·D slot rows (a [S, S] clock
+equality and a [S, S] × [S, E] group-OR), and a synced population
+parks few removes: a remove parks only when it arrives ahead of the
+adds it covers. With no valid slot the epilogue is the identity (ctr
+unchanged, an empty compacted buffer, no overflow), so the wrapper
+tests on the device whether any slot is valid and skips it when none is.
 """
 
 from __future__ import annotations
@@ -380,15 +387,26 @@ def _fold_fused_jit(
 
     # Deferred epilogue (stock jnp; see module docstring): union every
     # replica's parked removes, replay once, drop caught-up, compact.
+    # Skipped when no slot is valid, where it is the identity.
     d = states.dcl.shape[-2]
-    dcl = states.dcl.reshape(r * d, a)
-    dmask = states.dmask.reshape(r * d, e)
-    dvalid = states.dvalid.reshape(r * d)
-    dcl, dmask, dvalid = _dedupe_deferred(dcl, dmask, dvalid)
-    ctr = _apply_parked(ctr, dcl, dmask, dvalid)
-    still_ahead = ~jnp.all(dcl <= top[None, :], axis=-1)
-    dvalid = dvalid & still_ahead
-    dcl, dmask, dvalid, overflow = _compact_deferred(dcl, dmask, dvalid, d)
+
+    def settle():
+        dcl = states.dcl.reshape(r * d, a)
+        dmask = states.dmask.reshape(r * d, e)
+        dvalid = states.dvalid.reshape(r * d)
+        dcl, dmask, dvalid = _dedupe_deferred(dcl, dmask, dvalid)
+        out = _apply_parked(ctr, dcl, dmask, dvalid)
+        still_ahead = ~jnp.all(dcl <= top[None, :], axis=-1)
+        dvalid = dvalid & still_ahead
+        return (out, *_compact_deferred(dcl, dmask, dvalid, d))
+
+    def skip():
+        return (ctr, jnp.zeros((d, a), states.dcl.dtype),
+                jnp.zeros((d, e), states.dmask.dtype),
+                jnp.zeros((d,), states.dvalid.dtype), jnp.zeros((), bool))
+
+    ctr, dcl, dmask, dvalid, overflow = lax.cond(
+        jnp.any(states.dvalid), settle, skip)
     return (
         OrswotState(top=top, ctr=ctr, dcl=dcl, dmask=dmask, dvalid=dvalid),
         jnp.any(overflow),
@@ -423,12 +441,13 @@ def fold_fused_level(
     the leaf entry slab folds in ONE Pallas HBM pass exactly as
     ``fold_fused`` does, and every deferred-buffer level settles once in
     a jnp epilogue — leaf member-removes first (flatten R·D slots →
-    dedupe → replay → drop caught-up → compact), then each keyset level
-    innermost-out through the level's own ``settle_outer`` (dedupe →
-    replay → compact → scrub). Same once-at-the-end soundness argument
-    as the plain fold (module docstring): replay is monotone, idempotent
-    zeroing and always precedes the catch-up drop; the per-level
-    property gates in tests/test_pallas_fold.py pin fused == tree.
+    dedupe → replay → drop caught-up → compact, skipped when none is
+    valid), then each keyset level innermost-out through the level's
+    own ``settle_outer`` (dedupe → replay → compact → scrub). Same
+    once-at-the-end soundness argument as the plain fold (module
+    docstring): replay is monotone, idempotent zeroing and always
+    precedes the catch-up drop; the per-level property gates in
+    tests/test_pallas_fold.py pin fused == tree.
 
     Returns ``(state, flags[L])`` with ``NestLevel.fold``'s lane order
     (innermost level first)."""

@@ -84,6 +84,159 @@ def test_fused_fold_with_parked_removes():
     np.testing.assert_array_equal(np.asarray(tree.top), np.asarray(fused.top))
 
 
+# ---- the epilogue's skip when no parked slot is valid --------------------
+
+_PR, _PD, _PE, _PA = 32, 4, 16, 4  # R·D = 128 slots
+
+
+def _parked_population(seed, n_valid, live):
+    """R = 32 replicas of one causal history (actor a's counter c born on
+    element ``born[a, c]``; a replica holds the newest dot of each actor
+    on each element up to its top) with ``n_valid`` valid parked slots of
+    D = 4. Their clocks repeat across replicas (dedupe): ``live`` clocks
+    ahead of every top, and two that the folded top covers (replayed,
+    then dropped). The tree fold's root joins the even replicas' fold
+    with the odd ones', so each parity draws from at most D clocks: no
+    join below the root truncates, and ``live`` = D + 1 overflows at the
+    root alone. Invalid slots hold stale payload."""
+    rng = np.random.default_rng(seed)
+    c = np.arange(17)
+    top = rng.integers(4, 17, (_PR, _PA))
+    top[0] = 16
+    born = rng.integers(0, _PE, (_PA, 17))
+    seen = ((born[None, None] == np.arange(_PE)[None, :, None, None])
+            & (c > 0) & (c <= top[:, None, :, None]))
+    ctr = np.where(seen, c, 0).max(axis=-1)
+    dcl = rng.integers(0, 17, (_PR, _PD, _PA))
+    dmask = rng.random((_PR, _PD, _PE)) < 0.2
+    dvalid = np.zeros((_PR, _PD), bool)
+    ahead = rng.integers(0, 9, (live, _PA))
+    ahead[np.arange(live), rng.integers(0, _PA, live)] = 17 + np.arange(live)
+    caught = list(rng.integers(0, 9, (2, _PA)))
+    if live >= _PD:
+        pools = (list(ahead[:_PD - 1]), list(ahead[live - _PD + 1:]))
+    else:
+        pools = (list(ahead), list(ahead))
+    slots = np.arange(_PR * _PD).reshape(_PR, _PD)
+    for parity, count in ((0, n_valid // 2), (1, n_valid - n_valid // 2)):
+        pool = pools[parity] + [caught[parity]]
+        for i, p in enumerate(rng.permutation(slots[parity::2].ravel())[:count]):
+            dcl[p // _PD, p % _PD] = pool[i % len(pool)]
+            dvalid[p // _PD, p % _PD] = True
+    u32 = lambda x: jnp.asarray(x, jnp.uint32)  # noqa: E731
+    return ops.OrswotState(top=u32(top), ctr=u32(ctr), dcl=u32(dcl),
+                           dmask=jnp.asarray(dmask), dvalid=jnp.asarray(dvalid))
+
+
+def _full_epilogue(states):
+    """The fused fold's epilogue over every R·D slot, never skipped, on
+    the tree fold's join of the entries alone."""
+    from crdt_tpu.ops.orswot import _apply_parked, _compact_deferred, _dedupe_deferred
+
+    r, d = states.dvalid.shape
+    e, a = states.ctr.shape[-2:]
+    entries, _ = ops.fold(states._replace(dvalid=jnp.zeros_like(states.dvalid)))
+    dcl, dmask, dvalid = _dedupe_deferred(
+        states.dcl.reshape(r * d, a), states.dmask.reshape(r * d, e),
+        states.dvalid.reshape(r * d))
+    ctr = _apply_parked(entries.ctr, dcl, dmask, dvalid)
+    dvalid = dvalid & ~jnp.all(dcl <= entries.top[None, :], axis=-1)
+    dcl, dmask, dvalid, over = _compact_deferred(dcl, dmask, dvalid, d)
+    return ops.OrswotState(entries.top, ctr, dcl, dmask, dvalid), over
+
+
+def _slot_rows(state):
+    """The parked slots as a sorted list of (valid, clock, mask) rows."""
+    return sorted(
+        (bool(v), tuple(c), tuple(m)) for v, c, m in zip(
+            np.asarray(state.dvalid), np.asarray(state.dcl).tolist(),
+            np.asarray(state.dmask).tolist()))
+
+
+@pytest.mark.parametrize("n_valid,live", [
+    (0, 0), (1, 1), (63, 4), (64, 4), (64, 5), (65, 4), (65, 5), (128, 4), (128, 5),
+])
+def test_fused_fold_epilogue_matches_tree_fold(n_valid, live):
+    """From no valid slot (the skipped epilogue) to all R·D of them,
+    ``fold_fused`` equals the tree fold on top, ctr, dvalid and the
+    overflow flag, and equals the epilogue run over all R·D slots bit for
+    bit, slot order included. ``live`` D or D + 1 clocks survive the
+    catch-up drop; at D + 1 the flag is up, and which D slots stay is the
+    flat order's (the tree's order differs)."""
+    for seed in (n_valid, 1000 + n_valid):
+        states = _parked_population(seed, n_valid, live)
+        got, over = fold_fused(states, tile_e=8, interpret=True)
+        tree, tree_over = ops.fold(states)
+        full, full_over = _full_epilogue(states)
+        assert bool(over) == bool(tree_over) == bool(full_over) == (live > _PD)
+        assert int(np.asarray(got.dvalid).sum()) == min(live, _PD)
+        for name in got._fields:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, name)), np.asarray(getattr(full, name)),
+                err_msg=name)
+        for name in ("top", "ctr", "dvalid"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, name)), np.asarray(getattr(tree, name)),
+                err_msg=name)
+        if live <= _PD:
+            assert _slot_rows(got) == _slot_rows(tree)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of its sub-jaxprs, outside any
+    ``pallas_call`` (the kernel body is not the epilogue)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("r", [32, 8])
+def test_fused_fold_epilogue_lowers_to_one_conditional(r):
+    """The epilogue lowers for the chip to one conditional beside the
+    kernel: its skip branch holds no dot, and every dot of the settling
+    branch spans the R·D slot axis."""
+    from crdt_tpu.ops.pallas_kernels import _fold_fused_jit
+
+    d = 4
+    states = jax.eval_shape(lambda: ops.empty(256, 4, d, batch=(r,)))
+    text = _fold_fused_jit.trace(states, 128, 8, False).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    assert text.count("stablehlo.case") == 1
+
+    jaxpr = jax.make_jaxpr(lambda s: _fold_fused_jit(s, 128, 8, False))(states)
+    conds = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    skip, settle = conds[0].params["branches"]  # lax.cond(p, settle, skip)
+    dots = lambda j: [e for e in _eqns(j.jaxpr) if e.primitive.name == "dot_general"]  # noqa: E731
+    assert not dots(skip)
+    assert dots(settle)
+    assert all(any(r * d in v.aval.shape for v in e.invars) for e in dots(settle))
+
+
+@pytest.mark.parametrize("n_valid,live", [(0, 0), (128, 4)])
+def test_mesh_fold_fused_settles_parked_removes(n_valid, live):
+    """``mesh_fold`` with the fused local fold on a 1×1 mesh answers as
+    the tree fold does, with no valid slot and with every slot valid."""
+    from crdt_tpu.parallel import anti_entropy, make_mesh
+
+    states = _parked_population(7, n_valid, live)
+    out, over = anti_entropy.mesh_fold(states, make_mesh(1, 1), local_fold="fused")
+    tree, tree_over = ops.fold(states)
+    assert bool(over) == bool(tree_over)
+    for name in ("top", "ctr", "dvalid"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(out, name)), np.asarray(getattr(tree, name)),
+            err_msg=name)
+    assert _slot_rows(out) == _slot_rows(tree)
+
+
 def test_fold_auto_rejects_unknown_prefer():
     from crdt_tpu.ops import orswot as oo
     from crdt_tpu.ops.pallas_kernels import fold_auto
