@@ -25,7 +25,7 @@ import numpy as np
 
 from . import serde
 from .models.glist import BatchedGList
-from .models.list import BatchedList
+from .models.list import CHUNK_WIDTH, LANES, BatchedList, slot_width
 from .models.map import BatchedMap
 from .models.map3 import BatchedMap3
 from .models.map_nested import BatchedMapOrswot, BatchedNestedMap
@@ -316,6 +316,7 @@ def _dump(model) -> tuple:
         meta = {
             "kind": "list",
             "n_replicas": model.n_replicas,
+            "chunk_width": model.chunk_width,
             "applied": model._applied,
             "placed": model._mesh is not None,
         }
@@ -622,7 +623,7 @@ def _restore(meta: dict, arrays: dict):
         model.state = _state_from_arrays(model.state, arrays)
         return model
     if meta["kind"] == "list":
-        model = BatchedList(meta["n_replicas"])
+        model = BatchedList(meta["n_replicas"], meta.get("chunk_width", CHUNK_WIDTH))
         if meta.get("placed"):
             import warnings
 
@@ -637,12 +638,18 @@ def _restore(meta: dict, arrays: dict):
         assert (model.engine.total_order() == model.slots).all(), (
             "restored identifier order drifted from the checkpoint"
         )
-        model.vals = jax.device_put(arrays["vals"])
-        model.alive = jax.device_put(arrays["alive"])
+        vals, alive = arrays["vals"], arrays["alive"]
+        if vals.ndim == 2:
+            # Written while the state was [R, N]: lay it out in LANES-slot rows.
+            pad = ((0, 0), (0, slot_width(vals.shape[1]) - vals.shape[1]))
+            vals, alive = (np.pad(x, pad).reshape(len(x), -1, LANES) for x in (vals, alive))
+        model.vals = jax.device_put(vals)
+        model.alive = jax.device_put(alive)
         model.op_handles = arrays["op_handles"]
         model.op_kinds = arrays["op_kinds"]
         model.op_vals = arrays["op_vals"]
         model._applied = int(meta["applied"])
+        model.build_chunks()
         return model
     if meta["kind"] == "glist":
         model = BatchedGList(meta["n_replicas"])

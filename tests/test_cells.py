@@ -21,9 +21,12 @@ from tiny import TINY  # noqa: E402
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     BENCHMARK = json.load(_f)
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
-# The map cell's tiny shape, as its own CPU rehearsal cuts it.
+# The map cell's tiny shape, as its own CPU rehearsal cuts it; the list
+# cell's keeps the trace's insert share and a quarter of rows lagging.
 SHAPES = dict(TINY, map_mvreg_fold_1chip=dict(
-    replicas=16, keys=2048, writer_puts=4, writer_removes=4, check_span=8))
+    replicas=16, keys=2048, writer_puts=4, writer_removes=4, check_span=8),
+    list_automerge_1chip=dict(replicas=16, trace_ops=4096, trace_inserts=2875, chunk=128,
+                              lagged_replicas=4, max_lag=2))
 
 
 def run_tiny(cell, seed, seconds, trace=0, control=0):

@@ -221,3 +221,42 @@ def test_map_fold_spans_record_under_a_profiler_and_nothing_otherwise(tmp_path):
     kids = sum(st[name]["total_s"] for name in want if name != "ae.map_fold")
     assert st["ae.map_fold"]["self_s"] == pytest.approx(st["ae.map_fold"]["total_s"] - kids)
     assert set(want) <= _host_annotations(str(tmp_path / "trace"))
+
+
+def test_list_apply_spans_and_counters_record_what_the_call_did(tmp_path):
+    """``BatchedList.apply_chunks``'s entry span and its prep / dispatch /
+    wait children under a profiler session, and none off it; the lane
+    counters and the universe gauge read what each call did, on or off."""
+    from crdt_tpu.models import BatchedList
+    from crdt_tpu.native import DELETE, INSERT
+
+    # Chunk 0 types a, b, c and deletes b: three live lanes (b's insert
+    # is superseded in the chunk). Chunk 1 types one more: one lane.
+    model = BatchedList.from_trace(
+        [INSERT, INSERT, INSERT, DELETE, INSERT], [0, 1, 2, 1, 0], [1, 2, 3, 0, 4],
+        [0] * 5, n_replicas=3, chunk_width=4)
+
+    def counted():
+        c = metrics.snapshot()["counters"]
+        return c.get("list.lanes", 0), c.get("list.lanes_live", 0)
+
+    lanes0, live0 = counted()
+    model.apply_chunks([0, 0, -1])
+    assert tele.span_stats() == {} and tele.drain_events() == []
+    assert counted() == (lanes0 + 12, live0 + 6)
+    assert metrics.snapshot()["gauges"]["list.universe"]["last"] == 4
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        model.apply_chunks([1, 1, 0])
+    assert counted() == (lanes0 + 24, live0 + 11)
+    assert [model.read(r) for r in range(3)] == [[4, 1, 3], [4, 1, 3], [1, 3]]
+    parents = {}
+    for e in tele.drain_events():
+        parents.setdefault(e["name"], set()).add(e["parent"])
+    want = {"list.apply": {None}, "list.apply.prep": {"list.apply"},
+            "list.apply.dispatch": {"list.apply"}, "list.apply.wait": {"list.apply"}}
+    assert parents == want
+    st = tele.span_stats()
+    assert all(st[name]["n"] == 1 for name in want)
+    kids = sum(st[name]["total_s"] for name in want if name != "list.apply")
+    assert st["list.apply"]["self_s"] == pytest.approx(st["list.apply"]["total_s"] - kids)
+    assert set(want) <= _host_annotations(str(tmp_path / "trace"))

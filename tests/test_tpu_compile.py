@@ -160,3 +160,30 @@ def test_mesh_fold_four_chips(topo, tpu_dispatch):
     )
     compiled = _compile(lambda s: mesh_fold(s, mesh)[0], st)
     assert "collective-permute" in compiled.as_text()
+
+
+def test_list_apply_config5(one_chip):
+    """The ``list_automerge_1chip`` cell's apply: 1,024 replicas of the
+    182,315-identifier universe take one 1,024-lane chunk each from a
+    254-chunk device op log. The state is donated, so the program's
+    output aliases it; its layout makes the compiler's flattened scatter
+    a bitcast, so no copy of the state is made (temporaries under a
+    hundredth of it); and the round fits the chip."""
+    from crdt_tpu.models.list import LANES, _donated, _list_apply_chunks, slot_width
+
+    r, n, chunks, width = 1024, 182315, 254, 1024
+    state = (r, slot_width(n) // LANES, LANES)
+
+    def arg(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _donated(_list_apply_chunks).lower(
+        arg(*state), arg(*state, dtype=jnp.bool_), arg(chunks, width),
+        arg(chunks, width, dtype=jnp.uint8), arg(chunks, width), arg(r),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= r * n * 5, mem
+    assert mem.temp_size_in_bytes < r * n * 5 // 100, mem
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < HBM_BYTES, used
